@@ -15,10 +15,14 @@ MODULES = ["fig2_metric_pk", "fig3_k_quartiles", "fig46_fit",
            "fig10_scalability", "roofline", "bench_service"]
 
 
-def main() -> None:
+def main() -> int:
+    from repro.launch.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     want = [a for a in sys.argv[1:] if not a.startswith("-")]
     mods = [m for m in MODULES if not want or any(w in m for w in want)]
     print("name,us_per_call,derived")
+    failed = []
     for mod_name in mods:
         t0 = time.time()
         try:
@@ -29,7 +33,11 @@ def main() -> None:
         except Exception:
             print(f"# {mod_name} FAILED:", flush=True)
             traceback.print_exc()
+            failed.append(mod_name)
+    if failed:
+        print(f"# failed: {', '.join(failed)}", flush=True)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

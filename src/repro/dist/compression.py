@@ -13,7 +13,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -57,8 +56,8 @@ def make_int8_ef_allreduce(mesh: Mesh, axes: tuple[str, ...]):
     def compress(grads, ef):
         specs_in = (jax.tree.map(lambda _: rep, grads),
                     jax.tree.map(lambda _: rep, ef))
-        fn = shard_map(body, mesh=mesh, in_specs=specs_in,
-                       out_specs=specs_in, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=specs_in,
+                           out_specs=specs_in, check_vma=False)
         return fn(grads, ef)
 
     return init, compress

@@ -49,11 +49,8 @@ from repro.exec.plan import QueryPlan
 from repro.exec.sharded import (_pad_to, build_sharded_pipeline,
                                 place_sharded_corpus)
 from repro.kernels.lsh_probe import PAD_CORPUS
+from repro.kernels.ops import interpret_mode
 from repro.kernels.profile_distance import dequantize, quantize_profiles
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # Ingest deltas are padded up to this many rows before the on-device row
@@ -324,20 +321,24 @@ class Executor:
         # same buffers, so a claim through either must stick for both).
         self._host_lock = threading.Lock()
         self._host_spare = [False]
-        self._gbdt = tuple(map(jnp.asarray, gbdt_tuple))
+        self.mesh = mesh
+        # local plans run on the mesh's first device — a one-device mesh
+        # pins a fleet replica's corpus and executables to its own chip;
+        # no mesh leaves placement to JAX's default device
+        self._device = None if mesh is None else mesh.devices.flat[0]
+        self._gbdt = tuple(self._put(a) for a in gbdt_tuple)
         self._gbdt_bundle = PlacementBundle(
             {f"gbdt{i}": a for i, a in enumerate(self._gbdt)})
-        self.mesh = mesh
         self.score_block = int(score_block)
         # device-resident copies for the local pipelines
-        self._z = jnp.asarray(self._z_np)
-        self._zscale = jnp.asarray(self._zscale_np)
-        self._w = jnp.asarray(self._w_np)
-        self._cids = jnp.asarray(self._cids_np)
-        self._tids = jnp.asarray(self._tids_np)
-        self._ckeys = (jnp.asarray(self._ckeys_np)
+        self._z = self._put(self._z_np)
+        self._zscale = self._put(self._zscale_np)
+        self._w = self._put(self._w_np)
+        self._cids = self._put(self._cids_np)
+        self._tids = self._put(self._tids_np)
+        self._ckeys = (self._put(self._ckeys_np)
                        if self._ckeys_np is not None else None)
-        self._coarse = (jnp.asarray(self._coarse_np)
+        self._coarse = (self._put(self._coarse_np)
                         if self._coarse_np is not None else None)
         self._rows_bundle = PlacementBundle(dict(
             z=self._z, zscale=self._zscale, w=self._w, cids=self._cids,
@@ -366,6 +367,16 @@ class Executor:
         self._events = events
         self._seen_shapes: set[tuple] = set()
         self._tls = threading.local()
+
+    def _put(self, x, dtype=None):
+        """Place a local-pipeline operand on this executor's device."""
+        return jnp.asarray(x, dtype, device=self._device)
+
+    def _local_desc(self):
+        """Cache-signature geometry of a local unit: the pinned device, so
+        one replica never loads an executable built for another's chip."""
+        return None if self._device is None else ("device",
+                                                  int(self._device.id))
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -443,6 +454,7 @@ class Executor:
         ex.profile_dtype = self.profile_dtype
         ex.survivor_block = self.survivor_block
         ex.mesh = self.mesh
+        ex._device = self._device
         ex.score_block = self.score_block
         ex._zscale_np = self._zscale_np
         ex._zf_np = None
@@ -553,14 +565,14 @@ class Executor:
             ex._zscale = self._zscale        # per-feature: no row axis
         else:
             # bucket boundary crossed: re-place at the new padded size
-            ex._z = jnp.asarray(ex._z_np)
+            ex._z = ex._put(ex._z_np)
             ex._zscale = self._zscale
-            ex._w = jnp.asarray(ex._w_np)
-            ex._cids = jnp.asarray(ex._cids_np)
-            ex._tids = jnp.asarray(ex._tids_np)
-            ex._ckeys = (jnp.asarray(ex._ckeys_np)
+            ex._w = ex._put(ex._w_np)
+            ex._cids = ex._put(ex._cids_np)
+            ex._tids = ex._put(ex._tids_np)
+            ex._ckeys = (ex._put(ex._ckeys_np)
                          if ex._ckeys_np is not None else None)
-            ex._coarse = (jnp.asarray(ex._coarse_np)
+            ex._coarse = (ex._put(ex._coarse_np)
                           if ex._coarse_np is not None else None)
             ex.bytes_uploaded = sum(
                 int(a.nbytes) for a in (ex._z, ex._w, ex._cids, ex._tids,
@@ -633,7 +645,7 @@ class Executor:
                 budget_per_shard=(plan.budget_per_shard
                                   if plan.candidates != "all" else None),
                 shard_axes=axes, query_axes=qaxes, block=self.score_block,
-                interpret=_interpret())
+                interpret=interpret_mode())
         return self._pipelines[key]
 
     # -- AOT warmup ---------------------------------------------------------
@@ -804,7 +816,7 @@ class Executor:
             units.append(dict(
                 key=self._exe_key(name, q, statics, n_columns=c_over),
                 name=name, q=q,
-                statics=statics, dyn=dyn, mesh_desc=None,
+                statics=statics, dyn=dyn, mesh_desc=self._local_desc(),
                 lower=lambda fn=fn, dyn=dyn, statics=statics:
                     fn.lower(*dyn, **statics)))
             if self._fp32_rows is not None:
@@ -824,7 +836,7 @@ class Executor:
         return dict(key=self._exe_key("_rescore_exact", q, statics, (r,),
                                       n_columns=n_columns),
                     name="_rescore_exact", q=q, statics=statics, dyn=dyn,
-                    mesh_desc=None,
+                    mesh_desc=self._local_desc(),
                     lower=lambda dyn=dyn, k=k:
                         _rescore_exact.lower(*dyn, k=k))
 
@@ -930,10 +942,10 @@ class Executor:
         # may be an unpadded view (invalid slots are -1 → row 0, already
         # excluded by the scan's non-finite score)
         safe = np.clip(ids_np, 0, self.n_live - 1)
-        dyn = (jnp.asarray(zq, jnp.float32), jnp.asarray(wq),
-               jnp.asarray(np.asarray(self._fp32_rows(safe), np.float32)),
-               jnp.asarray(self._w_np[safe]), self._gbdt,
-               jnp.asarray(np.asarray(sc)), jnp.asarray(ids_np))
+        dyn = (self._put(zq, jnp.float32), self._put(wq),
+               self._put(self._fp32_rows(safe), jnp.float32),
+               self._put(self._w_np[safe]), self._gbdt,
+               self._put(np.asarray(sc)), self._put(ids_np))
         return self._call("_rescore_exact", _rescore_exact, dyn,
                           dict(k=k), extra=(int(ids_np.shape[1]),))
 
@@ -986,10 +998,10 @@ class Executor:
             return ("_local_tiered", _local_tiered,
                     dict(k=min(k, budget, surv), budget=min(budget, surv),
                          survivor_budget=surv, block_c=self.survivor_block,
-                         interpret=_interpret()))
+                         interpret=interpret_mode()))
         return ("_local_pruned", _local_pruned,
                 dict(kind=plan.candidates, k=min(k, budget), budget=budget,
-                     interpret=_interpret()))
+                     interpret=interpret_mode()))
 
     def _local_dyn(self, plan: QueryPlan, zq, wq, tq, qid, qkeys, qcoarse,
                    n_columns: int | None = None):
@@ -1031,11 +1043,11 @@ class Executor:
                     axes=axes, grid=plan.grid if qaxes else ())
 
     def _execute_local(self, plan, zq, wq, tq, qid, qkeys, qcoarse=None):
-        zq, wq = jnp.asarray(zq, jnp.float32), jnp.asarray(wq)
-        tq = jnp.asarray(tq, jnp.int32)
-        qid = jnp.asarray(qid, jnp.int32)
-        qkeys = jnp.asarray(qkeys) if qkeys is not None else None
-        qcoarse = jnp.asarray(qcoarse) if qcoarse is not None else None
+        zq, wq = self._put(zq, jnp.float32), self._put(wq)
+        tq = self._put(tq, jnp.int32)
+        qid = self._put(qid, jnp.int32)
+        qkeys = self._put(qkeys) if qkeys is not None else None
+        qcoarse = self._put(qcoarse) if qcoarse is not None else None
         name, fn, statics = self._local_spec(plan)
         dyn = self._local_dyn(plan, zq, wq, tq, qid, qkeys, qcoarse)
         out = self._call(name, fn, dyn, statics)

@@ -86,8 +86,8 @@ def place_sharded_corpus(mesh: Mesh, shard_axes, z: np.ndarray, w: np.ndarray,
 
 def build_sharded_pipeline(mesh: Mesh, gbdt_tuple, *, candidates: str = "all",
                            k: int, budget_per_shard: int | None = None,
-                           shard_axes=("data",), query_axes=(),
-                           block: int = 4096, interpret: bool = True):
+                           interpret: bool, shard_axes=("data",),
+                           query_axes=(), block: int = 4096):
     """Jitted grid-sharded candidate→score→merge pipeline over ``mesh``.
 
     ``candidates="all"``: fn(z, w, cids, tids, zq, wq, tq, qid);
@@ -99,8 +99,6 @@ def build_sharded_pipeline(mesh: Mesh, gbdt_tuple, *, candidates: str = "all",
     query batch must be divisible by the query-axis size (the executor
     pads).
     """
-    from jax.experimental.shard_map import shard_map
-
     axes = tuple(shard_axes)
     qaxes = tuple(query_axes)
     qspec = P(qaxes) if qaxes else P()
@@ -141,6 +139,6 @@ def build_sharded_pipeline(mesh: Mesh, gbdt_tuple, *, candidates: str = "all",
         in_specs = (P(axes), P(axes), P(axes), P(axes), P(axes),
                     qspec, qspec, qspec, qspec, qspec)
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(), P(), P()), check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(fn)

@@ -59,7 +59,7 @@ def exclusion_mask(cids, tids, tq, qid):
 
 
 def candidate_priorities(kind: str, zq, qkeys, z, ckeys, cids, tids, tq, qid,
-                         *, interpret: bool = True):
+                         *, interpret: bool):
     """(Q, C) float32 priorities; -inf means "never a candidate".
 
     ``kind``: ``lsh`` — bucket hits only (missing the budget is fine: the
@@ -84,8 +84,8 @@ def candidate_priorities(kind: str, zq, qkeys, z, ckeys, cids, tids, tq, qid,
 
 
 def tiered_survivors(qcoarse, coarse, cids, tids, tq, qid, *,
-                     survivor_budget: int, block_c: int = 32,
-                     proxy=None, interpret: bool = True):
+                     survivor_budget: int, interpret: bool,
+                     block_c: int = 32, proxy=None):
     """Coarse pass of the tiered candidate stage: pick survivor blocks.
 
     Probes the small (C, S) super-band digest with the (Q, S) coarse query
@@ -131,7 +131,7 @@ def tiered_survivors(qcoarse, coarse, cids, tids, tq, qid, *,
     return pos, valid, n_hits, n_survivors
 
 
-def tiered_priorities(zq, qkeys, zg, keys_g, valid, *, interpret: bool = True):
+def tiered_priorities(zq, qkeys, zg, keys_g, valid, *, interpret: bool):
     """Fine pass of the tiered stage over gathered survivors.
 
     ``zg`` (Q, M', F_NUM) and ``keys_g`` (Q, M', B) are the survivors'

@@ -55,8 +55,8 @@ def _kernel(qk_ref, ck_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_c", "interpret"))
-def lsh_probe_pallas(qkeys, ckeys, *, block_q: int = 8, block_c: int = 512,
-                     interpret: bool = True):
+def lsh_probe_pallas(qkeys, ckeys, *, interpret: bool, block_q: int = 8,
+                     block_c: int = 512):
     """qkeys (Q, B) u32, ckeys (C, B) u32 -> (Q, C) int32 hit mask."""
     q, b = qkeys.shape
     c = ckeys.shape[0]
@@ -78,8 +78,8 @@ def lsh_probe_pallas(qkeys, ckeys, *, block_q: int = 8, block_c: int = 512,
     return out[:q, :c]
 
 
-def lsh_probe_tile(qkeys, ckeys, *, block_q: int = 8, block_c: int = 512,
-                   interpret: bool = True):
+def lsh_probe_tile(qkeys, ckeys, *, interpret: bool, block_q: int = 8,
+                   block_c: int = 512):
     """Per-(Q-shard, C-shard) tile entry point for grid pipelines.
 
     Under a 2-D (query × data) ``shard_map`` each device probes only its
@@ -113,8 +113,8 @@ def _gathered_kernel(qk_ref, ck_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_c", "interpret"))
-def lsh_probe_gathered_pallas(qkeys, ckeys, *, block_q: int = 8,
-                              block_c: int = 256, interpret: bool = True):
+def lsh_probe_gathered_pallas(qkeys, ckeys, *, interpret: bool,
+                              block_q: int = 8, block_c: int = 256):
     """Fine probe over per-query gathered survivors.
 
     ``qkeys`` (Q, B) u32 against ``ckeys`` (Q, C', B) u32 — each query
@@ -150,8 +150,8 @@ def lsh_probe_gathered_pallas(qkeys, ckeys, *, block_q: int = 8,
     return out[:q, :cprime]
 
 
-def lsh_probe_gathered_tile(qkeys, ckeys, *, block_q: int = 8,
-                            block_c: int = 256, interpret: bool = True):
+def lsh_probe_gathered_tile(qkeys, ckeys, *, interpret: bool,
+                            block_q: int = 8, block_c: int = 256):
     """Dispatching entry point for the gathered fine probe: jnp reference
     when ``interpret`` is requested (CPU fallback), the Pallas kernel when
     compiling natively — same contract as ``lsh_probe_tile``."""

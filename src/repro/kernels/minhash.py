@@ -24,25 +24,31 @@ from repro.core import features as FT
 
 _SENT = np.uint32(FT.HASH_SENTINEL)
 _UMAX = np.uint32(0xFFFFFFFF)
+# Mosaic has no unsigned integer reductions: flipping the sign bit maps
+# uint32 order onto int32 order, so the kernel reduces in int32 and the
+# wrapper flips the bit back — bit-identical to an unsigned minimum.
+_SIGN = np.uint32(0x80000000)
+_IMAX = np.int32(0x7FFFFFFF)
 
 
 def _kernel(vals_ref, a_ref, b_ref, out_ref):
     @pl.when(pl.program_id(1) == 0)
     def _init():
-        out_ref[...] = jnp.full(out_ref.shape, _UMAX, jnp.uint32)
+        out_ref[...] = jnp.full(out_ref.shape, _IMAX, jnp.int32)
 
     v = vals_ref[...]                                  # (Cb, Rb) u32
     a = a_ref[...][0]                                  # (P,) u32
     b = b_ref[...][0]
     h = v[:, :, None] * a[None, None, :] + b[None, None, :]
     h = jnp.where(v[:, :, None] == _SENT, _UMAX, h)
-    m = jnp.min(h, axis=1)                             # (Cb, P)
+    h = jax.lax.bitcast_convert_type(h ^ _SIGN, jnp.int32)
+    m = jnp.min(h, axis=1)                             # (Cb, P) i32
     out_ref[...] = jnp.minimum(out_ref[...], m)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_r", "interpret"))
-def minhash_pallas(values, a, b, *, block_c: int = 8, block_r: int = 256,
-                   interpret: bool = True):
+def minhash_pallas(values, a, b, *, interpret: bool, block_c: int = 8,
+                   block_r: int = 256):
     """values (C, R) u32 sentinel-padded, a/b (P,) u32 -> (C, P) u32."""
     c, r = values.shape
     p = a.shape[0]
@@ -59,10 +65,10 @@ def minhash_pallas(values, a, b, *, block_c: int = 8, block_r: int = 256,
             pl.BlockSpec((1, p), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_c, p), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((cp, p), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((cp, p), jnp.int32),
         interpret=interpret,
     )(vp, a[None], b[None])
-    return out[:c]
+    return jax.lax.bitcast_convert_type(out[:c], jnp.uint32) ^ _SIGN
 
 
 def make_permutations(n_perm: int = 128, seed: int = 0):

@@ -43,6 +43,7 @@ from repro.core import (DiscoveryIndex, GBDTConfig, LakeSpec, generate_lake,
                         profile_lake, rank, select_queries,
                         train_quality_model)
 from repro.core.predictor import JoinQualityModel
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def serve_mode(args, lake, model):
@@ -185,6 +186,7 @@ def fleet_mode(args, lake, model, cost_fn, grid) -> None:
 
     from repro.service import (DiscoveryRequest, EngineConfig, EngineFleet,
                                LSHConfig, serve_discovery)
+    from repro.service.fleet import SERVING
 
     fleet = EngineFleet.from_catalog(
         args.catalog, model,
@@ -197,9 +199,13 @@ def fleet_mode(args, lake, model, cost_fn, grid) -> None:
         n_replicas=args.replicas,
         devices=jax.devices() if args.mesh else None)
     try:
-        fleet.warm_event.wait(timeout=300)
+        if not fleet.warm_event.wait(timeout=300):
+            raise SystemExit("fleet: no replica finished warming in 300 s")
         st = fleet.stats()
         slices = {rid: v["state"] for rid, v in st["replicas"].items()}
+        if SERVING not in slices.values():
+            raise SystemExit(f"fleet: no replica reached serving "
+                             f"(states {slices})")
         print(f"fleet: {args.replicas} replicas over "
               f"{len(jax.devices())} devices, states {slices}")
         metrics_server = None
@@ -342,6 +348,7 @@ def main():
                          "exposition on http://127.0.0.1:PORT/metrics "
                          "(0 = ephemeral port, printed at startup)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     t0 = time.perf_counter()
     lake = generate_lake(LakeSpec(n_domains=args.domains, n_tables=args.tables,

@@ -187,13 +187,14 @@ def lower_freyja_cell(mesh: Mesh, *, bf16_profiles: bool = False):
     from repro.configs import freyja_discovery as FD
     from repro.core import features as FT
     from repro.exec import build_sharded_pipeline
+    from repro.kernels.ops import interpret_mode
     n, q, k = FD.N_COLUMNS, FD.N_QUERIES, FD.TOP_K
     zdt = jnp.bfloat16 if bf16_profiles else jnp.float32
     ba = shd.batch_axes(mesh)
     gb = (jnp.zeros((50, 5), jnp.int32), jnp.zeros((50, 5), jnp.float32),
           jnp.zeros((50, 32), jnp.float32), jnp.float32(0.5))
     fn = build_sharded_pipeline(mesh, gb, candidates="all", k=k,
-                                shard_axes=ba)
+                                interpret=interpret_mode(), shard_axes=ba)
     shard = NamedSharding(mesh, P(ba))
     shard2 = NamedSharding(mesh, P(ba, None))
     rep = NamedSharding(mesh, P())

@@ -33,12 +33,14 @@ def make_replica_meshes(n_replicas: int, *, devices=None,
     ids share ICI links, so a contiguous slice keeps each replica's
     collectives on-chip instead of crossing the fleet boundary.
 
-    When the pool is too small to give every replica ``model`` devices
-    (e.g. 4 host devices, 8 replicas) every replica gets ``None`` —
-    single-device local execution, the degenerate slice.  A non-dividing
-    replica count leaves the trailing remainder devices unused rather
-    than building lopsided slices (uneven replicas would defeat the
-    router's cost symmetry).
+    A one-device slice (4 chips, 4 replicas) is a ``(1, 1)`` mesh: the
+    replica serves local plans, and the executor pins its corpus and
+    executables to that device.  When the pool is too small to give every
+    replica ``model`` devices (e.g. 4 host devices, 8 replicas) every
+    replica gets ``None`` — they share JAX's default device.  A
+    non-dividing replica count leaves the trailing remainder devices
+    unused rather than building lopsided slices (uneven replicas would
+    defeat the router's cost symmetry).
     """
     import numpy as np
 
@@ -48,8 +50,8 @@ def make_replica_meshes(n_replicas: int, *, devices=None,
     if n_replicas < 1:
         raise ValueError(f"n_replicas must be >= 1; got {n_replicas}")
     per = devs.size // n_replicas
-    if per < max(model, 1) or per < 2:
-        # not enough devices to mesh every replica: single-device local
+    if per < max(model, 1):
+        # not enough devices to give every replica its own slice
         return [None] * n_replicas
     per -= per % model                   # keep the model axis dividing
     meshes = []

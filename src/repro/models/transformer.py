@@ -122,7 +122,6 @@ def _moe_apply(pm, cfg, x, mesh):
         y = moe_mod.moe_ffn_local(pm, cfg, x2, model_axis=None)
     else:
         import numpy as np
-        from jax.experimental.shard_map import shard_map
         from repro.dist.sharding import spec_of
         ba = _batch_axes(mesh)
         nba = int(np.prod([mesh.shape[a] for a in ba])) if ba else 1
@@ -141,8 +140,8 @@ def _moe_apply(pm, cfg, x, mesh):
         def local_fn(pm_, x_):
             return moe_mod.moe_ffn_local(pm_, cfg, x_, model_axis=maxis)
 
-        fn = shard_map(local_fn, mesh=mesh, in_specs=(pspecs, xspec),
-                       out_specs=xspec, check_rep=False)
+        fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(pspecs, xspec),
+                           out_specs=xspec, check_vma=False)
         y = fn(pm, x2)
     return y.reshape(b, s, d)
 

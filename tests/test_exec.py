@@ -263,15 +263,17 @@ def test_candidate_priorities_lsh_vs_hybrid(rng):
     tq = jnp.asarray([-1])
     qid = jnp.asarray([-1])
     lsh = np.asarray(candidate_priorities("lsh", jnp.asarray(zq), qkeys, z,
-                                          ckeys, cids, tids, tq, qid))
+                                          ckeys, cids, tids, tq, qid,
+                                          interpret=True))
     assert np.isfinite(lsh[0, 3]) and np.isinf(lsh[0, :3]).all()
     hyb = np.asarray(candidate_priorities("hybrid", jnp.asarray(zq), qkeys,
-                                          z, ckeys, cids, tids, tq, qid))
+                                          z, ckeys, cids, tids, tq, qid,
+                                          interpret=True))
     assert np.isfinite(hyb).all()            # proxy fills the whole lake
     assert hyb[0].argmax() == 3              # the bucket hit still outranks
     with pytest.raises(ValueError):
         candidate_priorities("nope", jnp.asarray(zq), qkeys, z, ckeys, cids,
-                             tids, tq, qid)
+                             tids, tq, qid, interpret=True)
 
 
 # ---------------------------------------------------------------------------

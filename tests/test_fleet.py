@@ -437,3 +437,62 @@ def test_router_prefers_cheap_replica_under_load():
     snaps = [ReplicaSnapshot(0, SERVING, 0, 1.0),
              ReplicaSnapshot(1, SERVING, 100, 0.001)]
     assert r.choose(snaps, n_items=8) == 0
+
+
+# ---------------------------------------------------------------------------
+# replica placement: one device per replica
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_SCRIPT = r"""
+import jax, numpy as np
+from repro.core import features as FT
+from repro.exec import Executor, Planner, PlannerConfig
+from repro.launch.mesh import make_replica_meshes
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+assert make_replica_meshes(8) == [None] * 8
+meshes = make_replica_meshes(4)
+assert [m.devices.size for m in meshes] == [1, 1, 1, 1]
+rng = np.random.default_rng(0)
+c = 300
+z = rng.normal(size=(c, FT.F_NUM)).astype(np.float32)
+w = rng.integers(0, 50, (c, FT.F_WORDS)).astype(np.uint32)
+keys = rng.integers(0, 2**31, (c, 8)).astype(np.uint32)
+gb = (rng.integers(0, FT.F_DIST, (4, 3)).astype(np.int32),
+      rng.normal(size=(4, 3)).astype(np.float32),
+      rng.normal(size=(4, 8)).astype(np.float32), 0.5)
+q = np.arange(5)
+plan = Planner(PlannerConfig(k=5)).plan(n_columns=c, n_queries=5, mode="lsh")
+want = Executor(z, w, gb, band_keys=keys).execute(
+    plan, z[q], w[q], np.full(5, -1, np.int32), q.astype(np.int32), keys[q])
+for i, m in enumerate(meshes):
+    ex = Executor(z, w, gb, band_keys=keys, mesh=m)
+    assert ex._z.devices() == {devs[i]}, (i, ex._z.devices())
+    ex.aot_compile([(plan, 5)])
+    got = ex.execute(plan, z[q], w[q], np.full(5, -1, np.int32),
+                     q.astype(np.int32), keys[q])
+    assert ex.dispatch_stats() == {"aot": 1, "fallback": 0}
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+print("ok")
+"""
+
+
+def test_one_device_replica_slices_pin_their_device():
+    """Four replicas on four devices each get a one-device mesh, and the
+    executor places corpus and AOT executables on that device (with the
+    same results as default placement); more replicas than devices still
+    share the default device."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.path.join(root, "src")
+    r = subprocess.run([sys.executable, "-c", _PLACEMENT_SCRIPT],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0 and "ok" in r.stdout, r.stdout + r.stderr
