@@ -7,7 +7,8 @@ still re-trace and re-compile the whole bucket ladder.  This module
 persists the compiled executables across processes:
 
 * entries are keyed by a blake2b digest over a **signature dict** —
-  jax version, backend, device kind and count, the XLA flags, the mesh
+  jax version, backend, device kind and count, the XLA flags, a digest
+  of the package's sources (the pipelines are traced from them), the mesh
   geometry (axis names/sizes + shard/query axes for sharded pipelines),
   the pipeline name with its static arguments (plan kind, k, budget,
   survivor geometry, kernel tile/block config), and the shapes+dtypes of
@@ -31,6 +32,7 @@ restart land in milliseconds.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -40,10 +42,24 @@ from pathlib import Path
 
 import jax
 
-# Bump whenever a pipeline's jaxpr changes without its signature moving
-# (signatures hash statics+avals, not the traced program): schema 2 =
-# live-column counting in ``_local_all`` for bucket-padded corpora.
-_SCHEMA = 2
+# Bump when the entry format changes: schema 3 = keys carry the digest
+# of the program's sources.
+_SCHEMA = 3
+
+
+@functools.lru_cache(maxsize=None)
+def source_digest() -> str:
+    """blake2b digest of every ``.py`` file under ``src/repro``: statics
+    and avals do not move when a source a pipeline is traced from is
+    edited, so the key carries this digest and an edited pipeline never
+    meets a stale entry. Digesting the whole package, not a list of the
+    pipelines' imports, means no import can be missed."""
+    root = Path(__file__).resolve().parents[1]
+    h = hashlib.blake2b(digest_size=16)
+    for path in sorted(root.rglob("*.py")):
+        h.update(path.relative_to(root).as_posix().encode())
+        h.update(path.read_bytes())
+    return h.hexdigest()
 
 
 def environment_signature() -> dict:
@@ -52,6 +68,7 @@ def environment_signature() -> dict:
     devs = jax.devices()
     return {
         "schema": _SCHEMA,
+        "sources": source_digest(),
         "jax": jax.__version__,
         "backend": jax.default_backend(),
         "device_kind": devs[0].device_kind if devs else "none",

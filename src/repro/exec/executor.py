@@ -43,7 +43,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import features as FT
-from repro.exec import stages
+from repro.exec import spans, stages
 from repro.exec.aot import tree_aval_descriptors
 from repro.exec.plan import QueryPlan
 from repro.exec.sharded import (_pad_to, build_sharded_pipeline,
@@ -200,9 +200,13 @@ def _local_pruned(zq, wq, qkeys, tq, qid, z, zscale, w, ckeys, cids, tids,
     prio = stages.candidate_priorities(kind, zq, qkeys, zf, ckeys, cids,
                                        tids, tq, qid, interpret=interpret)
     pos, valid = stages.gather_candidates(prio, budget)
-    s = stages.score_columns(zq, wq, zf[pos], w[pos], gbdt_tuple)
+    with jax.named_scope("candidates"):
+        zc, wc = zf[pos], w[pos]
+    s = stages.score_columns(zq, wq, zc, wc, gbdt_tuple)
     s = jnp.where(valid, s, -jnp.inf)
-    sc, ids = stages.merge_topk(s, cids[pos], k)
+    with jax.named_scope("candidates"):
+        cand_ids = cids[pos]
+    sc, ids = stages.merge_topk(s, cand_ids, k)
     return sc, ids, valid.sum(axis=1).astype(jnp.int32)
 
 
@@ -219,21 +223,27 @@ def _local_tiered(zq, wq, qkeys, qcoarse, tq, qid, z, zscale, w, ckeys,
     top-k's profile-similar-but-non-overlapping columns are unreachable
     and large-lake recall trails the single-tier hybrid probe."""
     zf = dequantize(z, zscale)
-    # -||zq - z||² up to a per-query constant, fused over the sidecar
-    fill = 2.0 * zq @ zf.T - jnp.sum(zf * zf, axis=1)[None]
+    with jax.named_scope("candidates"):
+        # -||zq - z||² up to a per-query constant, fused over the sidecar
+        fill = 2.0 * zq @ zf.T - jnp.sum(zf * zf, axis=1)[None]
     pos, valid, n_hits, n_surv = stages.tiered_survivors(
         qcoarse, coarse, cids, tids, tq, qid,
         survivor_budget=survivor_budget, block_c=block_c, proxy=fill,
         interpret=interpret)
-    zg = dequantize(z[pos], zscale)                      # (Q, M', F_NUM)
-    prio = stages.tiered_priorities(zq, qkeys, zg, ckeys[pos], valid,
+    with jax.named_scope("candidates"):
+        zg = dequantize(z[pos], zscale)                  # (Q, M', F_NUM)
+        keys_g = ckeys[pos]
+    prio = stages.tiered_priorities(zq, qkeys, zg, keys_g, valid,
                                     interpret=interpret)
     pos2, valid2 = stages.gather_candidates(prio, budget)
-    gpos = jnp.take_along_axis(pos, pos2, axis=1)        # (Q, M) global cols
-    s = stages.score_columns(zq, wq, dequantize(z[gpos], zscale), w[gpos],
-                             gbdt_tuple)
+    with jax.named_scope("candidates"):
+        gpos = jnp.take_along_axis(pos, pos2, axis=1)    # (Q, M) global cols
+        zc, wc = dequantize(z[gpos], zscale), w[gpos]
+    s = stages.score_columns(zq, wq, zc, wc, gbdt_tuple)
     s = jnp.where(valid2, s, -jnp.inf)
-    sc, ids = stages.merge_topk(s, cids[gpos], k)
+    with jax.named_scope("candidates"):
+        cand_ids = cids[gpos]
+    sc, ids = stages.merge_topk(s, cand_ids, k)
     return sc, ids, valid2.sum(axis=1).astype(jnp.int32), n_hits, n_surv
 
 
@@ -901,8 +911,9 @@ class Executor:
             # re-rank their returned k — ordering repaired, no recovery
             # of ids the quantized scan dropped)
             sc, ids = self._rescore(zq, wq, sc, ids, plan.k)
-        sc, ids = pad_topk(np.asarray(sc), np.asarray(ids), plan.k)
-        n = np.asarray(n)               # block until ready before timing
+        with spans.span("executor.fetch"):
+            sc, ids = pad_topk(np.asarray(sc), np.asarray(ids), plan.k)
+            n = np.asarray(n)           # block until ready before timing
         if first:
             wall_ms = (time.perf_counter() - t0) * 1e3
             self._tls.compile_ms = wall_ms
@@ -937,15 +948,17 @@ class Executor:
         """Gather the scan's candidate rows from the fp32 source and
         re-rank them exactly.  The gather is (Q, R, F) with R a small
         multiple of k, so the cost is independent of the lake size."""
-        ids_np = np.asarray(ids)
+        with spans.span("executor.fetch"):
+            ids_np, sc_np = np.asarray(ids), np.asarray(sc)
         # clip to live rows, not the bucket-padded corpus: the fp32 source
         # may be an unpadded view (invalid slots are -1 → row 0, already
         # excluded by the scan's non-finite score)
         safe = np.clip(ids_np, 0, self.n_live - 1)
-        dyn = (self._put(zq, jnp.float32), self._put(wq),
-               self._put(self._fp32_rows(safe), jnp.float32),
-               self._put(self._w_np[safe]), self._gbdt,
-               self._put(np.asarray(sc)), self._put(ids_np))
+        with spans.span("executor.put"):
+            dyn = (self._put(zq, jnp.float32), self._put(wq),
+                   self._put(self._fp32_rows(safe), jnp.float32),
+                   self._put(self._w_np[safe]), self._gbdt,
+                   self._put(sc_np), self._put(ids_np))
         return self._call("_rescore_exact", _rescore_exact, dyn,
                           dict(k=k), extra=(int(ids_np.shape[1]),))
 
@@ -968,11 +981,12 @@ class Executor:
         dynamic args are passed), else the plain jitted fallback."""
         exe = self._compiled.get(
             self._exe_key(name, dyn[0].shape[0], statics, extra))
-        if exe is not None:
-            self._dispatch_stats["aot"] += 1
-            return exe(*dyn)
-        self._dispatch_stats["fallback"] += 1
-        return fn(*dyn, **statics)
+        self._dispatch_stats["aot" if exe is not None else "fallback"] += 1
+        with spans.span("executor.dispatch", program=name,
+                        aot=int(exe is not None)):
+            if exe is not None:
+                return exe(*dyn)
+            return fn(*dyn, **statics)
 
     def dispatch_stats(self) -> dict:
         """AOT vs jit-fallback dispatch counts — a warmed engine serving
@@ -1043,17 +1057,23 @@ class Executor:
                     axes=axes, grid=plan.grid if qaxes else ())
 
     def _execute_local(self, plan, zq, wq, tq, qid, qkeys, qcoarse=None):
-        zq, wq = self._put(zq, jnp.float32), self._put(wq)
-        tq = self._put(tq, jnp.int32)
-        qid = self._put(qid, jnp.int32)
-        qkeys = self._put(qkeys) if qkeys is not None else None
-        qcoarse = self._put(qcoarse) if qcoarse is not None else None
+        with spans.span("executor.put"):
+            zq, wq = self._put(zq, jnp.float32), self._put(wq)
+            tq = self._put(tq, jnp.int32)
+            qid = self._put(qid, jnp.int32)
+            qkeys = self._put(qkeys) if qkeys is not None else None
+            qcoarse = self._put(qcoarse) if qcoarse is not None else None
         name, fn, statics = self._local_spec(plan)
         dyn = self._local_dyn(plan, zq, wq, tq, qid, qkeys, qcoarse)
         out = self._call(name, fn, dyn, statics)
         if plan.candidates == "tiered":
             sc, ids, n, n_hits, n_surv = out
-            self._tls.tier_stats = (np.asarray(n_hits), np.asarray(n_surv))
+            # only the event bus reads the coarse-pass counts: without
+            # one, the batch pays no blocking copy for them
+            if self._events is not None:
+                with spans.span("executor.fetch"):
+                    self._tls.tier_stats = (np.asarray(n_hits),
+                                            np.asarray(n_surv))
             return sc, ids, n
         return out
 
@@ -1071,23 +1091,23 @@ class Executor:
         qsharding = NamedSharding(mesh, P(qaxes) if qaxes else P())
         put = lambda a, dt=None: jax.device_put(
             np.asarray(a, dt) if dt else np.asarray(a), qsharding)
-        if plan.candidates == "all":
-            args = (corpus["z"], corpus["w"], corpus["cids"],
-                    corpus["tids"], put(zq, np.float32), put(wq),
-                    put(tq, np.int32), put(qid, np.int32))
-        else:
-            args = (corpus["z"], corpus["w"], corpus["cids"],
-                    corpus["tids"], corpus["ckeys"],
-                    put(zq, np.float32), put(wq),
-                    put(qkeys, np.uint32), put(tq, np.int32),
-                    put(qid, np.int32))
+        with spans.span("executor.put"):
+            if plan.candidates == "all":
+                args = (corpus["z"], corpus["w"], corpus["cids"],
+                        corpus["tids"], put(zq, np.float32), put(wq),
+                        put(tq, np.int32), put(qid, np.int32))
+            else:
+                args = (corpus["z"], corpus["w"], corpus["cids"],
+                        corpus["tids"], corpus["ckeys"],
+                        put(zq, np.float32), put(wq),
+                        put(qkeys, np.uint32), put(tq, np.int32),
+                        put(qid, np.int32))
         key = self._exe_key("sharded", np.asarray(zq).shape[0],
                             self._sharded_statics(plan))
         exe = self._compiled.get(key)
-        if exe is not None:
-            self._dispatch_stats["aot"] += 1
-            sc, ids, n = exe(*args)
-        else:
-            self._dispatch_stats["fallback"] += 1
-            sc, ids, n = self._pipeline(plan)(*args)
-        return sc[:q], ids[:q], n[:q]
+        self._dispatch_stats["aot" if exe is not None else "fallback"] += 1
+        with spans.span("executor.dispatch", program="sharded",
+                        aot=int(exe is not None)):
+            sc, ids, n = (exe if exe is not None
+                          else self._pipeline(plan))(*args)
+            return sc[:q], ids[:q], n[:q]

@@ -132,9 +132,13 @@ def build_sharded_pipeline(mesh: Mesh, gbdt_tuple, *, candidates: str = "all",
                 interpret=interpret)
             m = min(budget_per_shard, z.shape[0])
             pos, valid = stages.gather_candidates(prio, m)
-            s = stages.score_columns(zq, wq, z[pos], w[pos], gbdt_tuple)
+            with jax.named_scope("candidates"):
+                zc, wc = z[pos], w[pos]
+            s = stages.score_columns(zq, wq, zc, wc, gbdt_tuple)
             s = jnp.where(valid, s, -jnp.inf)
-            return _merge(s, cids[pos], valid.sum(axis=1).astype(jnp.int32))
+            with jax.named_scope("candidates"):
+                cand_ids = cids[pos]
+            return _merge(s, cand_ids, valid.sum(axis=1).astype(jnp.int32))
 
         in_specs = (P(axes), P(axes), P(axes), P(axes), P(axes),
                     qspec, qspec, qspec, qspec, qspec)

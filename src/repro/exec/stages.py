@@ -18,8 +18,16 @@ and inside ``shard_map`` — ``executor.py`` composes them into the local
 pipelines, ``sharded.py`` into the per-device bodies.  Column ids are
 always *global* (``cids``), so exclusion masks (self, same-table, padding)
 work unchanged on a shard.
+
+Each stage function traces under its stage's ``jax.named_scope``
+(``candidates``, ``score``, ``merge``), so every op of a compiled pipeline
+carries its stage in its HLO metadata (``op_name``) and a device profile
+can sum op time by stage under names no compiler edit changes. Scopes
+touch metadata only, never the compiled arithmetic.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +40,17 @@ CANDIDATE_KINDS = ("all", "lsh", "hybrid", "tiered")
 # LSH hits outrank every profile-proximity score: the proxy is squashed
 # into (-1, 1), so any offset > 2 keeps the two bands disjoint.
 _LSH_PRIORITY_BOOST = 4.0
+
+
+def stage(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -58,6 +77,7 @@ def exclusion_mask(cids, tids, tq, qid):
     return pad | self_hit | same_table
 
 
+@stage("candidates")
 def candidate_priorities(kind: str, zq, qkeys, z, ckeys, cids, tids, tq, qid,
                          *, interpret: bool):
     """(Q, C) float32 priorities; -inf means "never a candidate".
@@ -83,6 +103,7 @@ def candidate_priorities(kind: str, zq, qkeys, z, ckeys, cids, tids, tq, qid,
     return jnp.where(excl, -jnp.inf, prio)
 
 
+@stage("candidates")
 def tiered_survivors(qcoarse, coarse, cids, tids, tq, qid, *,
                      survivor_budget: int, interpret: bool,
                      block_c: int = 32, proxy=None):
@@ -131,6 +152,7 @@ def tiered_survivors(qcoarse, coarse, cids, tids, tq, qid, *,
     return pos, valid, n_hits, n_survivors
 
 
+@stage("candidates")
 def tiered_priorities(zq, qkeys, zg, keys_g, valid, *, interpret: bool):
     """Fine pass of the tiered stage over gathered survivors.
 
@@ -146,6 +168,7 @@ def tiered_priorities(zq, qkeys, zg, keys_g, valid, *, interpret: bool):
     return jnp.where(valid, prio, -jnp.inf)
 
 
+@stage("candidates")
 def gather_candidates(prio, budget: int):
     """Top-``budget`` columns by priority -> (positions (Q, M), valid (Q, M)).
 
@@ -160,6 +183,7 @@ def gather_candidates(prio, budget: int):
 # stage 2: scoring
 # ---------------------------------------------------------------------------
 
+@stage("score")
 def score_columns(zq, wq, zc, wc, gbdt_tuple):
     """GBDT join-quality scores. zc/wc (C, F) -> (Q, C); an extra leading
     axis on zc/wc ((Q, M, F) gathered candidates) scores per-query sets."""
@@ -169,6 +193,7 @@ def score_columns(zq, wq, zc, wc, gbdt_tuple):
     return gbdt_predict_ref(gbdt_tuple, d)
 
 
+@stage("score")
 def score_streamed(zq, wq, z, w, gbdt_tuple, *, block: int = 4096):
     """Full-corpus scoring, streamed in column blocks of ``block``.
 
@@ -195,6 +220,7 @@ def score_streamed(zq, wq, z, w, gbdt_tuple, *, block: int = 4096):
 # stage 3: top-k merge
 # ---------------------------------------------------------------------------
 
+@stage("merge")
 def merge_topk(scores, cids, k: int):
     """Local top-k -> (scores (Q, k'), global ids (Q, k')), k' = min(k, C).
 
@@ -209,6 +235,7 @@ def merge_topk(scores, cids, k: int):
     return sc, jnp.where(jnp.isfinite(sc), ids, -1)
 
 
+@stage("merge")
 def merge_topk_sharded(local_scores, local_ids, k: int, axes):
     """Per-device top-k results -> replicated global top-k.
 

@@ -64,7 +64,8 @@ from repro.core import features as FT
 from repro.core.ingest import ingest_string_columns
 from repro.core.predictor import JoinQualityModel
 from repro.exec import (DEFAULT_BATCH_BUCKETS, MODES, Executor,
-                        ExecutableCache, Planner, PlannerConfig, pad_rows)
+                        ExecutableCache, Planner, PlannerConfig, pad_rows,
+                        spans)
 from repro.kernels.profile_distance import quantize_profiles_streamed
 from repro.service import events as EV
 from repro.service.api import ColumnMatch, DiscoveryRequest, DiscoveryResponse
@@ -213,6 +214,7 @@ class DiscoveryEngine:
         # (``events=``) is adopted as-is WITHOUT a private aggregator —
         # the fleet shares one bus + one ServiceMetrics across replicas
         self._closed = False
+        spans.install_gc_spans()
         self.events = events
         self.metrics = None
         if config.metrics and events is None:
@@ -648,8 +650,8 @@ class DiscoveryEngine:
         return self.query_batch([request])[0]
 
     def query_batch(self, requests: list[DiscoveryRequest], *,
-                    trace_ids: list[str] | None = None
-                    ) -> list[DiscoveryResponse]:
+                    trace_ids: list[str] | None = None,
+                    batch: int | None = None) -> list[DiscoveryResponse]:
         """Serve one micro-batch against one pinned snapshot version.
 
         Reentrant: the scheduler's worker, direct callers, and racing
@@ -660,27 +662,35 @@ class DiscoveryEngine:
         delivered the batch.  ``trace_ids`` threads the scheduler's
         per-submission ids through; direct callers get fresh ids (or the
         request's own ``trace_id``) and a trace whose spans sum to
-        ``compute_ms``."""
-        t0 = time.perf_counter()
-        if trace_ids is None:
-            trace_ids = [r.trace_id or EV.mint_trace_id() for r in requests]
-        self._maybe_follow()
-        st = self._pin()
+        ``compute_ms``.  ``batch``, the scheduler's batch sequence number,
+        labels the ``freyja.engine.execute`` profiler span."""
+        # contiguous phases: the clock's (phase, end) marks partition
+        # [t0, t_end], so the per-query span shares sum EXACTLY to
+        # compute_ms, and each phase is the profiler span of its name
+        clock = spans.PhaseClock("engine", "pin")
         try:
-            return self._query_pinned(st, requests, t0, trace_ids)
+            if trace_ids is None:
+                trace_ids = [r.trace_id or EV.mint_trace_id()
+                             for r in requests]
+            self._maybe_follow()
+            st = self._pin()
+            try:
+                return self._query_pinned(st, requests, clock, trace_ids,
+                                          batch)
+            finally:
+                self._release(st)
         finally:
-            self._release(st)
+            clock.close()
 
     def _query_pinned(self, st: _VersionState,
-                      requests: list[DiscoveryRequest], t0: float,
-                      trace_ids: list[str]) -> list[DiscoveryResponse]:
+                      requests: list[DiscoveryRequest],
+                      clock: spans.PhaseClock, trace_ids: list[str],
+                      batch: int | None) -> list[DiscoveryResponse]:
         if st.snapshot.n_columns == 0:
             return [DiscoveryResponse(name=r.name, matches=[],
                                       n_candidates=0, trace_id=tid)
                     for r, tid in zip(requests, trace_ids)]
-        # contiguous phase marks: (phase, t) pairs partition [t0, t_end]
-        # so the per-query span shares sum EXACTLY to compute_ms
-        marks: list[tuple[str, float]] = [("pin", time.perf_counter())]
+        clock.next("resolve")
         zq, wq, sigq, tq, qid = self._resolve(requests, st)
         keys = [self._cache_key(st, zq[i], wq[i], sigq[i], requests[i])
                 for i in range(len(requests))]
@@ -697,13 +707,13 @@ class DiscoveryEngine:
                     n_candidates=0, cached=True, trace_id=trace_ids[i])
             else:
                 todo.append(i)
-        marks.append(("resolve", time.perf_counter()))
 
         compile_ms = None
         if todo:
+            clock.next("plan")
             scores, ids, ncand, plan = self._rank_rows(
                 zq[todo], wq[todo], sigq[todo], tq[todo], qid[todo], st,
-                marks=marks)
+                clock=clock, batch=batch)
             compile_ms = st.executor.last_compile_ms()
             # the plan's cost was modeled for the PADDED batch — normalize
             # by that count, not len(todo), or a lone miss looks batch_pad×
@@ -718,6 +728,8 @@ class DiscoveryEngine:
                     matches=self._trim(matches, requests[i]),
                     n_candidates=int(ncand[row]), trace_id=trace_ids[i])
                 scored += int(ncand[row])
+        else:
+            clock.next("finalize")
 
         with self._slock:                  # one locked fold per batch
             self._counters["queries"] += len(requests)
@@ -737,22 +749,22 @@ class DiscoveryEngine:
                 self.events.publish(EV.CACHE_MISS, n=len(todo),
                                     trace_ids=[trace_ids[i] for i in todo],
                                     version=st.version)
-        t_end = time.perf_counter()
+        t_end = clock.close()
         n = max(len(requests), 1)
-        dt_ms = (t_end - t0) * 1e3 / n
-        spans = []
-        prev = t0
-        for phase, t in marks + [("finalize", t_end)]:
-            spans.append({"phase": phase, "ms": (t - prev) * 1e3 / n})
+        dt_ms = (t_end - clock.t0) * 1e3 / n
+        phases = []
+        prev = clock.t0
+        for phase, t in clock.marks:
+            phases.append({"phase": phase, "ms": (t - prev) * 1e3 / n})
             prev = t
         if compile_ms is not None:
-            for s in spans:                # annotate, never add a span —
+            for s in phases:               # annotate, never add a span —
                 if s["phase"] == "execute":  # the sum must stay exact
                     s["compile_ms"] = compile_ms
         for r in responses:
             r.compute_ms = dt_ms
             r.latency_ms = r.queue_ms + dt_ms
-            r.trace = r.trace + [dict(s) for s in spans]
+            r.trace = r.trace + [dict(s) for s in phases]
         return responses
 
     # -- observability ------------------------------------------------------
@@ -826,12 +838,14 @@ class DiscoveryEngine:
         return -(-max(int(n_queries), 1) // bp) * bp
 
     def _rank_rows(self, zq, wq, sigq, tq, qid,
-                   st: _VersionState | None = None, marks=None):
+                   st: _VersionState | None = None,
+                   clock: spans.PhaseClock | None = None,
+                   batch: int | None = None):
         """Plan + execute one padded micro-batch through ``repro.exec``.
 
-        ``marks`` (optional) collects contiguous ``(phase, t)`` trace
-        marks — plan / candidates / execute — for the caller's span
-        accounting."""
+        ``clock`` (optional), in its ``plan`` phase, is moved on through
+        ``candidates`` and ``execute`` (labelled with ``batch``) to
+        ``finalize`` for the caller's span accounting."""
         st = st if st is not None else self._head
         (zq, wq, sigq, tq, qid), q = pad_rows(
             (zq, wq, sigq, tq, qid),
@@ -845,18 +859,19 @@ class DiscoveryEngine:
         plan = self.planner.plan(n_columns=st.executor.n_columns,
                                  n_queries=pad, mode=self.config.mode,
                                  mesh=self.mesh, grid=self.config.grid)
-        if marks is not None:
-            marks.append(("plan", time.perf_counter()))
+        if clock is not None:
+            clock.next("candidates")
         qkeys = (st.lsh.query_keys(sigq) if plan.candidates != "all"
                  else None)
         qcoarse = (st.lsh.coarse_query_keys(sigq)
                    if plan.candidates == "tiered" else None)
-        if marks is not None:
-            marks.append(("candidates", time.perf_counter()))
+        if clock is not None:
+            clock.next("execute", **({} if batch is None
+                                     else {"batch": batch}))
         sc, ids, ncand = st.executor.execute(plan, zq, wq, tq, qid,
                                              qkeys=qkeys, qcoarse=qcoarse)
-        if marks is not None:
-            marks.append(("execute", time.perf_counter()))
+        if clock is not None:
+            clock.next("finalize")
         self.last_plan = plan
         with self._slock:
             self._plan_counts[plan.kind] = \

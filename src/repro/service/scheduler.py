@@ -61,6 +61,7 @@ import time
 import typing
 from concurrent.futures import Future, InvalidStateError
 
+from repro.exec import spans
 from repro.exec.plan import DEFAULT_BATCH_BUCKETS
 from repro.service import events as EV
 
@@ -114,21 +115,22 @@ def finalize_batch(items, responses, t_start: float, *, metrics=None) -> None:
     queue wait.  A future that already resolved (a re-dispatched batch
     whose abandoned first owner un-hung later) is left alone — the
     second resolution is swallowed, never raised into a worker thread."""
-    for it, r in zip(items, responses):
-        r.queue_ms = (t_start - it.t_submit) * 1e3
-        r.latency_ms = r.queue_ms + r.compute_ms
-        # prepend the scheduler-side spans: profile (measured at submit)
-        # and queue (the remainder of queue_ms), so the full trace still
-        # sums EXACTLY to latency_ms
-        r.trace = ([{"phase": "profile", "ms": it.profile_ms},
-                    {"phase": "queue", "ms": r.queue_ms - it.profile_ms}]
-                   + r.trace)
-        if metrics is not None:
-            metrics.observe_response(r)
-        try:
-            it.future.set_result(r)
-        except InvalidStateError:
-            pass
+    with spans.span("scheduler.deliver", n=len(items)):
+        for it, r in zip(items, responses):
+            r.queue_ms = (t_start - it.t_submit) * 1e3
+            r.latency_ms = r.queue_ms + r.compute_ms
+            # prepend the scheduler-side spans: profile (measured at
+            # submit) and queue (the remainder of queue_ms), so the full
+            # trace still sums EXACTLY to latency_ms
+            r.trace = ([{"phase": "profile", "ms": it.profile_ms},
+                        {"phase": "queue", "ms": r.queue_ms - it.profile_ms}]
+                       + r.trace)
+            if metrics is not None:
+                metrics.observe_response(r)
+            try:
+                it.future.set_result(r)
+            except InvalidStateError:
+                pass
 
 
 def fail_batch(items, exc: BaseException) -> None:
@@ -318,28 +320,15 @@ class RequestScheduler:
         """Block for arrivals, coalesce within the wait window, then pop
         up to ``max_batch`` items in priority order.  None = shut down."""
         with self._cv:
-            while not self._heap and not self._stop:
-                self._cv.wait()
+            if not self._heap and not self._stop:
+                with spans.span("scheduler.wait"):
+                    while not self._heap and not self._stop:
+                        self._cv.wait()
             if not self._heap:
                 return None                      # stopped and drained
             if self.config.max_wait_ms > 0 and not self._stop:
-                t_end = self._clock() + self.config.max_wait_ms / 1e3
-                while len(self._heap) < self.max_batch and not self._stop:
-                    # deadline-aware shrink: waiting past the earliest
-                    # queued deadline converts a live request into an
-                    # expiration, so the window is cut to that deadline —
-                    # the batch forms smaller but every admitted request
-                    # that can still make it, makes it
-                    bound = t_end
-                    for _, _, it in self._heap:
-                        if it.deadline is not None and it.deadline < bound:
-                            bound = it.deadline
-                    left = bound - self._clock()
-                    if left <= 0:
-                        if bound < t_end:
-                            self._counters["window_shrunk"] += 1
-                        break
-                    self._cv.wait(timeout=left)
+                with spans.span("scheduler.coalesce", queued=len(self._heap)):
+                    self._coalesce()
             # partition as we pop so expired requests never consume live
             # batch slots: keep drawing from the queue until max_batch
             # UNEXPIRED items are staged (or it drains) — a backlog of
@@ -374,6 +363,26 @@ class RequestScheduler:
                 self._counters["expired"] += n_expired
         return live
 
+    def _coalesce(self) -> None:
+        """Wait, lock held, until ``max_batch`` requests are queued or the
+        coalescing window closes."""
+        t_end = self._clock() + self.config.max_wait_ms / 1e3
+        while len(self._heap) < self.max_batch and not self._stop:
+            # deadline-aware shrink: waiting past the earliest queued
+            # deadline converts a live request into an expiration, so the
+            # window is cut to that deadline — the batch forms smaller but
+            # every admitted request that can still make it, makes it
+            bound = t_end
+            for _, _, it in self._heap:
+                if it.deadline is not None and it.deadline < bound:
+                    bound = it.deadline
+            left = bound - self._clock()
+            if left <= 0:
+                if bound < t_end:
+                    self._counters["window_shrunk"] += 1
+                break
+            self._cv.wait(timeout=left)
+
     def _run_batch(self, items: list[_Item]) -> None:
         t_start = self._clock()
         n = len(items)
@@ -383,39 +392,42 @@ class RequestScheduler:
         # (sum(batch_size_hist) != batches mid-update)
         with self._cv:
             self._counters["batches"] += 1
+            seq = self._counters["batches"]
             self._batch_hist[n] = self._batch_hist.get(n, 0) + 1
             key = "bucket_hits" if n in self._bucket_set else "bucket_misses"
             self._counters[key] += 1
-        self._publish(EV.BATCH_FORMED, n=n,
-                      trace_ids=[it.trace_id for it in items])
-        if self._dispatch is not None:
-            # fleet handoff: the router places this formed batch on a
-            # replica; that replica's worker resolves the futures (via
-            # finalize_batch) and reports back through note_completed
-            self._dispatch(items)
-            return
-        try:
-            responses = self.engine.query_batch(
-                [it.request for it in items],
-                trace_ids=[it.trace_id for it in items])
-        except BaseException as e:
+        bucket = next((b for b in self.buckets if b >= n), n)
+        with spans.span("scheduler.batch", batch=seq, n=n, bucket=bucket):
+            self._publish(EV.BATCH_FORMED, n=n,
+                          trace_ids=[it.trace_id for it in items])
+            if self._dispatch is not None:
+                # fleet handoff: the router places this formed batch on a
+                # replica; that replica's worker resolves the futures (via
+                # finalize_batch) and reports back through note_completed
+                self._dispatch(items)
+                return
+            try:
+                responses = self.engine.query_batch(
+                    [it.request for it in items],
+                    trace_ids=[it.trace_id for it in items], batch=seq)
+            except BaseException as e:
+                with self._cv:
+                    self._counters["failed"] += n
+                for it in items:
+                    try:
+                        it.future.set_exception(e)
+                    except InvalidStateError:
+                        pass
+                return
+            finalize_batch(items, responses, t_start, metrics=self.metrics)
             with self._cv:
-                self._counters["failed"] += n
-            for it in items:
-                try:
-                    it.future.set_exception(e)
-                except InvalidStateError:
-                    pass
-            return
-        finalize_batch(items, responses, t_start, metrics=self.metrics)
-        with self._cv:
-            self._counters["completed"] += n
-        if self.metrics is not None:
-            # fold this batch's events into the registry now, so the
-            # metrics cursor tails the ring closely (zero-drop guarantee
-            # at any load the worker keeps up with) and a scrape between
-            # batches sees current counters
-            self.metrics.drain()
+                self._counters["completed"] += n
+            if self.metrics is not None:
+                # fold this batch's events into the registry now, so the
+                # metrics cursor tails the ring closely (zero-drop
+                # guarantee at any load the worker keeps up with) and a
+                # scrape between batches sees current counters
+                self.metrics.drain()
 
     # -- fleet reporting ----------------------------------------------------
 

@@ -59,3 +59,48 @@ def seeded_rng(request) -> np.random.Generator:
     coupling through a shared session rng)."""
     seed = zlib.crc32(request.node.nodeid.encode()) & 0xFFFFFFFF
     return np.random.default_rng(seed)
+
+
+def pruned_hlo(struct, *, q: int, c: int, budget: int, interpret: bool,
+               scopes: bool) -> str:
+    """Optimized HLO text of the ``_local_pruned`` pipeline (hybrid, k=10)
+    at batch ``q`` over ``c`` columns, with its metadata (``op_name``
+    scopes, source lines) and stack-frame tables stripped. ``struct(shape,
+    dtype)`` makes each argument's ``ShapeDtypeStruct``; ``scopes=False``
+    traces with every ``jax.named_scope`` a no-op."""
+    import contextlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.exec import executor
+
+    f_num, f_words, bands, trees, depth = 21, 11, 64, 50, 5
+    args = (struct((q, f_num), jnp.float32), struct((q, f_words), jnp.uint32),
+            struct((q, bands), jnp.uint32), struct((q,), jnp.int32),
+            struct((q,), jnp.int32), struct((c, f_num), jnp.float32),
+            struct((f_num,), jnp.float32), struct((c, f_words), jnp.uint32),
+            struct((c, bands), jnp.uint32), struct((c,), jnp.int32),
+            struct((c,), jnp.int32),
+            (struct((trees, depth), jnp.int32),
+             struct((trees, depth), jnp.float32),
+             struct((trees, 2 ** depth), jnp.float32),
+             struct((), jnp.float32)))
+    statics = dict(kind="hybrid", k=10, budget=budget, interpret=interpret)
+    # a fresh jit of the raw pipeline, so neither trace is served from the
+    # other's cache
+    fn = jax.jit(lambda *a, **kw: executor._local_pruned.__wrapped__(*a, **kw),
+                 static_argnames=tuple(statics))
+    named_scope = jax.named_scope
+    if not scopes:
+        jax.named_scope = lambda name: contextlib.nullcontext()
+    try:
+        text = fn.lower(*args, **statics).compile().as_text()
+    finally:
+        jax.named_scope = named_scope
+    # the stack-frame tables (FileNames ... StackFrames: header lines and
+    # numbered rows) name source lines; the ops follow them
+    text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames"
+                  r"|\d+ .*)\n", "", text)
+    return re.sub(r",? metadata=\{[^}]*\}", "", text)

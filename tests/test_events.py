@@ -390,3 +390,136 @@ class TestLoadgenAndStats:
         for t in ths:
             t.join(timeout=30)
         assert not errs, f"torn stats snapshot: {errs[0]}"
+
+
+# -- profiler spans -----------------------------------------------------------
+
+ENGINE_PHASES = ("pin", "resolve", "plan", "candidates", "execute",
+                 "finalize")
+
+
+def _profiled(tmp_path, work):
+    """Run ``work()`` inside a profiler session; return (its result, the
+    ``freyja.*`` host events as (line, name, start, end, stats))."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = work()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    evs = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("freyja."):
+                    evs.append((line.name, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out, sorted(evs, key=lambda e: e[2])
+
+
+def _inside(inner, outer):
+    return (inner[0] == outer[0] and outer[2] <= inner[2]
+            and inner[3] <= outer[3])
+
+
+class TestProfilerSpans:
+    def test_scheduler_engine_round_trip_records_every_span(self, snapshot,
+                                                            tmp_path):
+        import gc
+
+        eng = _engine(snapshot, metrics=False)
+
+        def work():
+            with RequestScheduler(eng, SchedulerConfig(max_wait_ms=1.0)) \
+                    as sch:
+                for rnd in range(2):
+                    futs = [sch.submit(DiscoveryRequest(
+                        name=f"r{rnd}_{i}", column_id=i)) for i in range(4)]
+                    for f in futs:
+                        f.result(timeout=60)
+            gc.collect()
+
+        _, evs = _profiled(tmp_path, work)
+        names = {e[1] for e in evs}
+        want = ({f"freyja.scheduler.{s}"
+                 for s in ("wait", "coalesce", "batch", "deliver")}
+                | {f"freyja.engine.{p}" for p in ENGINE_PHASES}
+                | {f"freyja.executor.{s}" for s in ("put", "dispatch",
+                                                    "fetch")}
+                | {"freyja.gc"})
+        assert want <= names, want - names
+        batches = [e for e in evs if e[1] == "freyja.scheduler.batch"]
+        assert len(batches) >= 2
+        seqs = [b[4]["batch"] for b in batches]
+        assert seqs == sorted(set(seqs))
+        for b in batches:
+            assert b[4]["n"] >= 1 and b[4]["bucket"] >= b[4]["n"]
+            execs = [e for e in evs if e[1] == "freyja.engine.execute"
+                     and _inside(e, b)]
+            assert len(execs) == 1
+            assert execs[0][4]["batch"] == b[4]["batch"]
+            ex = [e for e in evs if e[1].startswith("freyja.executor.")
+                  and _inside(e, execs[0])]
+            assert {e[1] for e in ex} == {"freyja.executor.put",
+                                          "freyja.executor.dispatch",
+                                          "freyja.executor.fetch"}
+            dispatch = [e for e in ex if e[1] == "freyja.executor.dispatch"]
+            assert [d[4]["program"] for d in dispatch] == ["_local_all"]
+            assert all(d[4]["aot"] in (0, 1) for d in dispatch)
+            assert [e for e in evs if e[1] == "freyja.scheduler.deliver"
+                    and _inside(e, b)][0][4]["n"] == b[4]["n"]
+        coalesce = [e for e in evs if e[1] == "freyja.scheduler.coalesce"]
+        assert all(e[4]["queued"] >= 1 for e in coalesce)
+        # the forced full collection, from the test's own thread
+        assert 2 in [e[4]["generation"] for e in evs if e[1] == "freyja.gc"]
+
+    def test_engine_phase_spans_are_the_trace_phases(self, snapshot,
+                                                     tmp_path):
+        eng = _engine(snapshot, metrics=False)
+        eng.query(DiscoveryRequest(name="warm", column_id=1))
+        resp, evs = _profiled(tmp_path, lambda: eng.query_batch(
+            [DiscoveryRequest(name=f"p{i}", column_id=i) for i in range(3)],
+            batch=7)[0])
+        phases = [e for e in evs if e[1].startswith("freyja.engine.")]
+        assert [e[1] for e in phases] == \
+            [f"freyja.engine.{s['phase']}" for s in resp.trace]
+        assert [s["phase"] for s in resp.trace] == list(ENGINE_PHASES)
+        # one clock: each phase span starts where the previous one ended
+        for a, b in zip(phases, phases[1:]):
+            assert 0 <= b[2] - a[3] < 1e6              # ns
+        assert [e[4].get("batch") for e in phases] == \
+            [None, None, None, None, 7, None]
+        # the per-request trace still sums to the latency
+        assert sum(s["ms"] for s in resp.trace) == \
+            pytest.approx(resp.latency_ms, abs=1e-9)
+
+    def test_phase_clock_marks_partition_its_interval(self):
+        from repro.exec.spans import PhaseClock
+
+        clock = PhaseClock("engine", "pin")
+        for phase in ENGINE_PHASES[1:]:
+            clock.next(phase)
+        t_end = clock.close()
+        assert clock.close() == t_end              # closing twice is a no-op
+        assert [p for p, _ in clock.marks] == list(ENGINE_PHASES)
+        ts = [clock.t0] + [t for _, t in clock.marks]
+        assert ts == sorted(ts) and ts[-1] == t_end
+
+    def test_gc_spans_install_once(self):
+        import gc
+
+        from repro.exec import spans
+
+        spans.install_gc_spans()
+        spans.install_gc_spans()
+        assert gc.callbacks.count(spans._on_gc) == 1

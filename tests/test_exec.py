@@ -300,6 +300,21 @@ def exec_setup():
     return lake, prof, model, ex, keys
 
 
+def test_stage_scopes_change_no_compiled_op():
+    """``named_scope`` on the candidate/score/merge stages lands in the
+    ops' metadata only: the optimized HLO of one bucket of the pruned
+    pipeline is the same with and without the scopes, op for op."""
+    import jax
+
+    from _fixtures import pruned_hlo
+
+    struct = jax.ShapeDtypeStruct
+    kw = dict(q=8, c=1024, budget=256, interpret=True)
+    scoped = pruned_hlo(struct, scopes=True, **kw)
+    assert scoped == pruned_hlo(struct, scopes=False, **kw)
+    assert " gather(" in scoped and " while(" in scoped
+
+
 def test_executor_full_matches_rank(exec_setup):
     from repro.core import DiscoveryIndex, rank, select_queries
     lake, prof, model, ex, keys = exec_setup

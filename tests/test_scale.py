@@ -302,6 +302,8 @@ def test_tiered_quantized_matches_tiered_fp32(scaled_snapshot, scaled_lake,
                          candidate_frac=0.2, cache_entries=0))
         tops[dt] = [[m.column_id for m in r.matches]
                     for r in eng.query_batch(reqs)]
+        # no event bus: the coarse-pass counts are never copied to the host
+        assert eng._executor._tls.tier_stats is None
     overlap = np.mean([len(set(a) & set(b)) / max(len(a), 1)
                        for a, b in zip(tops["fp32"], tops["int8"])])
     assert overlap >= 0.9

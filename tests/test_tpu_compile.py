@@ -75,3 +75,16 @@ def test_compile_minhash(one_chip):
                                            sharding=one_chip)
     _assert_tpu_kernel(minhash_pallas.lower(
         s((1024, 2048)), s((128,)), s((128,)), interpret=False))
+
+
+def test_stage_scopes_change_no_compiled_op(one_chip):
+    """The TPU compiler's program for one bucket (8 queries) of the pruned
+    pipeline is the same, op for op, with and without the stage scopes:
+    they reach the ops' metadata only."""
+    from _fixtures import pruned_hlo
+
+    struct = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    kw = dict(q=8, c=8192, budget=1024, interpret=False)
+    scoped = pruned_hlo(struct, scopes=True, **kw)
+    assert scoped == pruned_hlo(struct, scopes=False, **kw)
+    assert "tpu_custom_call" in scoped

@@ -131,7 +131,8 @@ def test_restart_reuses_persisted_executables(catalog_dir, model, tmp_path):
 
 @pytest.mark.parametrize("drift", [{"jax": "0.0.0-different"},
                                    {"device_kind": "TPU v9"},
-                                   {"n_devices": 1234}])
+                                   {"n_devices": 1234},
+                                   {"sources": "0" * 32}])
 def test_environment_drift_invalidates_entries(catalog_dir, model, tmp_path,
                                                drift):
     cache_dir = str(tmp_path / "cache")
@@ -144,6 +145,62 @@ def test_environment_drift_invalidates_entries(catalog_dir, model, tmp_path,
         cache_dir, env={**environment_signature(), **drift})
     rep = e2.warmup("serve")
     assert rep["cache_hits"] == 0 and rep["cache_misses"] == n
+
+
+def _digest_with_edit(monkeypatch, is_edited):
+    """``aot.source_digest()`` as it reads with a line appended to every
+    source file for which ``is_edited(path)`` holds."""
+    from pathlib import Path
+
+    from repro.exec import aot
+
+    read = Path.read_bytes
+
+    def edited(path):
+        data = read(path)
+        return data + b"\n# edited\n" if is_edited(path) else data
+
+    monkeypatch.setattr(Path, "read_bytes", edited)
+    aot.source_digest.cache_clear()
+    try:
+        return aot.source_digest()
+    finally:
+        monkeypatch.undo()
+        aot.source_digest.cache_clear()
+
+
+def test_edited_pipeline_source_changes_the_key(monkeypatch, tmp_path):
+    """An edit to a traced source moves every key: a warm cache can never
+    serve the executable of the pipeline as it was before the edit."""
+    from repro.exec import aot
+
+    before = aot.environment_signature()
+    assert before["sources"] == aot.source_digest()
+    after = {**before, "sources": _digest_with_edit(
+        monkeypatch, lambda path: path.name == "stages.py")}
+    assert after["sources"] != before["sources"]
+    sig = dict(name="_local_pruned", statics={"k": 10}, avals=[[[8], "f32"]])
+    old, new = (ExecutableCache(tmp_path, env=env) for env in (before, after))
+    assert new._path(new.signature(**sig)) != old._path(old.signature(**sig))
+    assert aot.environment_signature() == before
+
+
+@pytest.mark.parametrize("edited_file", ["core/gbdt.py", "core/quality.py",
+                                         "service/engine.py"])
+def test_edit_outside_exec_and_kernels_changes_the_key(monkeypatch,
+                                                       edited_file):
+    """The digest covers every source of the package, so an edit to a
+    module the pipelines reach through an import (``kernels/ops.py`` ->
+    ``core.gbdt``, ``kernels/ref.py`` -> ``core.quality``) moves it too."""
+    from pathlib import Path
+
+    from repro.exec import aot
+
+    target = Path(aot.__file__).resolve().parents[1] / edited_file
+    assert target.is_file()
+    before = aot.source_digest()
+    assert _digest_with_edit(monkeypatch, lambda p: p == target) != before
+    assert aot.source_digest() == before
 
 
 def test_corrupt_entries_fall_back_to_fresh_compiles(catalog_dir, model,
