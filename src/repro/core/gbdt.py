@@ -1,4 +1,4 @@
-"""Oblivious-tree gradient boosting — the paper's GBM, reshaped for the MXU.
+"""Oblivious-tree gradient boosting — the paper's GBM, reshaped for the TPU.
 
 The paper trains a Gradient Boosting regressor (50 estimators, default
 hyperparameters otherwise) on profile-difference vectors. A classical GBDT
@@ -8,14 +8,17 @@ analogue. We adapt the insight, not the implementation (DESIGN.md §2):
 so inference is
 
     leaf_index = Σ_level  (x[feat_l] ≥ thr_l) << l          (VPU compares)
-    prediction += one_hot(leaf_index, 2^depth) @ leaves      (MXU matmul)
+    prediction += leaves[leaf_index]
 
 which is branch-free and batchable — the same trick CatBoost uses on CPU
-SIMD. Training (histogram-based greedy, second-order boosting) runs offline
+SIMD. The served scorer (``core.predictor.gbdt_predict_ref``) picks the leaf
+with a select tree over the level compares, not a gather; the Pallas kernel
+``kernels/gbdt_infer`` (tests and paper benchmarks only) uses a one-hot
+matmul. Training (histogram-based greedy, second-order boosting) runs offline
 in numpy: the paper's model is trained once, off-line, and shipped; only
 inference must scale to lake size.
 
-Parameters are exported as dense arrays consumed by ``kernels/gbdt_infer``.
+Parameters are exported as dense arrays (``GBDTParams.astuple``).
 """
 from __future__ import annotations
 

@@ -146,20 +146,38 @@ def train_quality_model(lakes: list[Lake], cfg: GBDTConfig = GBDTConfig(),
 
 
 # ---------------------------------------------------------------------------
-# inference (jnp reference; discovery.py wires the Pallas kernels)
+# inference: the served scorer (exec/stages.score_columns calls it)
 # ---------------------------------------------------------------------------
 
+def _pick_leaf(bits, lv, offset: int, depth: int):
+    """Value of the leaf the level ``bits`` pick from the tree's leaf row
+    ``lv``: leaf index ``sum(bits[l] << l)``, chosen by selects from the top
+    level down (``2^depth - 1`` of them) over static slices of ``lv``."""
+    if depth == 0:
+        return lv[offset]
+    hi = offset + (1 << (depth - 1))
+    return jnp.where(bits[depth - 1], _pick_leaf(bits, lv, hi, depth - 1),
+                     _pick_leaf(bits, lv, offset, depth - 1))
+
+
 def gbdt_predict_ref(params_tuple, x: jnp.ndarray) -> jnp.ndarray:
-    """Pure-jnp oblivious-GBDT inference. x: (..., F) -> (...)."""
+    """Pure-jnp oblivious-GBDT inference. x: (..., F) -> (...).
+
+    This is the served scorer: every executor path scores through it (via
+    ``exec.stages.score_columns``), on every platform. Each tree's leaf is
+    picked with a select tree over its level compares rather than a gather
+    from the leaf row: a per-pair gather of one of ``2^D`` values costs a
+    TPU v5e ≈ 8 ns per element, the selects a few vector ops. The f32 sums
+    are the same (``base``, then one leaf per tree in order), and so are
+    the bits."""
     feats, thrs, leaves, base = params_tuple
-    t, d = feats.shape
+    d = feats.shape[1]
 
     def tree(carry, tp):
         f_l, t_l, lv = tp
         xf = jnp.take(x, f_l, axis=-1)                     # (..., D)
-        bits = (xf >= t_l).astype(jnp.int32)
-        idx = jnp.sum(bits * (2 ** jnp.arange(d, dtype=jnp.int32)), axis=-1)
-        return carry + jnp.take(lv, idx, axis=0), None
+        bits = [xf[..., lvl] >= t_l[lvl] for lvl in range(d)]
+        return carry + _pick_leaf(bits, lv, 0, d), None
 
     out, _ = jax.lax.scan(tree, jnp.full(x.shape[:-1], base, jnp.float32),
                           (jnp.asarray(feats), jnp.asarray(thrs), jnp.asarray(leaves)))

@@ -1,4 +1,5 @@
-"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+"""Compile-only checks of the main-path Pallas kernels and the scorer for a
+TPU v5e.
 
 Nothing runs on a chip: each kernel is lowered with ``interpret=False`` at
 real widths for a *described* ``v5e:2x2`` topology and compiled by the TPU
@@ -12,11 +13,14 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core import features as FT
+from repro.exec import stages
 from repro.kernels.lsh_probe import (lsh_probe_gathered_pallas,
                                      lsh_probe_pallas, lsh_probe_tile)
 from repro.kernels.minhash import minhash_pallas
@@ -88,3 +92,32 @@ def test_stage_scopes_change_no_compiled_op(one_chip):
     scoped = pruned_hlo(struct, scopes=True, **kw)
     assert scoped == pruned_hlo(struct, scopes=False, **kw)
     assert "tpu_custom_call" in scoped
+
+
+def _gather_operand_shapes(hlo: str) -> list[str]:
+    """Shape of the first operand of every gather in an optimized HLO
+    text, fused computations included (instruction names are unique in a
+    module, so each operand is found by its name)."""
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+\[[\d,]*\])",
+                               hlo, re.M))
+    return [shape_of[op] for op in
+            re.findall(r"= \w+\[[\d,]*\]\S* gather\((%[\w.-]+),", hlo)]
+
+
+def test_compile_scorer_reads_no_leaf_table_by_gather(one_chip):
+    """The scorer at the closed64 bucket (64 queries × 4096 gathered
+    candidates, 50 trees of depth 5): the only gather left is the feature
+    pick from the (64, 4096, 23) distances; none reads a leaf row."""
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    q, m, t, d = 64, 4096, 50, 5
+    gbdt = (s((t, d), jnp.int32), s((t, d), jnp.float32),
+            s((t, 2 ** d), jnp.float32), s((), jnp.float32))
+    hlo = jax.jit(stages.score_columns).lower(
+        s((q, FT.F_NUM), jnp.float32), s((q, FT.F_WORDS), jnp.uint32),
+        s((q, m, FT.F_NUM), jnp.float32), s((q, m, FT.F_WORDS), jnp.uint32),
+        gbdt).compile().as_text()
+    operands = _gather_operand_shapes(hlo)
+    assert operands, "the feature pick's gather should be found"
+    # a leaf row, or the whole leaf table, has 2^D values on its last axis
+    assert not [o for o in operands if re.search(rf"[\[,]{2 ** d}\]$", o)], \
+        operands
