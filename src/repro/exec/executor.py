@@ -144,12 +144,14 @@ RESCORE_MULT = 4
 @partial(jax.jit, static_argnames=("k",))
 def _rescore_exact(zq, wq, zg, wg, gbdt_tuple, sc_scan, ids, k: int):
     """Re-rank an over-fetched (Q, R) candidate set with exact fp32
-    profiles; invalid scan slots (non-finite score) stay excluded."""
-    s = stages.score_columns(zq, wq, zg, wg, gbdt_tuple)
-    s = jnp.where(jnp.isfinite(sc_scan), s, -jnp.inf)
-    sc, pos = jax.lax.top_k(s, min(k, s.shape[1]))
-    return sc, jnp.where(jnp.isfinite(sc),
-                         jnp.take_along_axis(ids, pos, axis=1), -1)
+    profiles; invalid scan slots (non-finite score) stay excluded. Traced
+    under the ``rescore`` scope (its scoring under ``rescore/score``)."""
+    with jax.named_scope("rescore"):
+        s = stages.score_columns(zq, wq, zg, wg, gbdt_tuple)
+        s = jnp.where(jnp.isfinite(sc_scan), s, -jnp.inf)
+        sc, pos = jax.lax.top_k(s, min(k, s.shape[1]))
+        return sc, jnp.where(jnp.isfinite(sc),
+                             jnp.take_along_axis(ids, pos, axis=1), -1)
 
 
 def pad_rows(arrays, multiple: int):
@@ -222,8 +224,8 @@ def _local_tiered(zq, wq, qkeys, qcoarse, tq, qid, z, zscale, w, ckeys,
     left empty with profile-nearest columns — without the fill, the exact
     top-k's profile-similar-but-non-overlapping columns are unreachable
     and large-lake recall trails the single-tier hybrid probe."""
-    zf = dequantize(z, zscale)
-    with jax.named_scope("candidates"):
+    with jax.named_scope("candidates"), jax.named_scope("coarse"):
+        zf = dequantize(z, zscale)
         # -||zq - z||² up to a per-query constant, fused over the sidecar
         fill = 2.0 * zq @ zf.T - jnp.sum(zf * zf, axis=1)[None]
     pos, valid, n_hits, n_surv = stages.tiered_survivors(
@@ -900,20 +902,33 @@ class Executor:
                                  grid=list(plan.grid), n_queries=q, k=plan.k)
         t0 = time.perf_counter()
         self._tls.tier_stats = None
+        tier = None
         if plan.sharded:
             sc, ids, n = self._execute_sharded(plan, zq, wq, tq, qid, qkeys)
         else:
-            sc, ids, n = self._execute_local(plan, zq, wq, tq, qid, qkeys,
-                                             qcoarse)
+            sc, ids, n, tier = self._execute_local(plan, zq, wq, tq, qid,
+                                                   qkeys, qcoarse)
+        counts = () if tier is None else (tier["hits"], tier["survivors"])
         if self._fp32_rows is not None:
             # exact fp32 re-rank of the quantized scan's top set (local
             # scans over-fetched RESCORE_MULT × k above; sharded scans
             # re-rank their returned k — ordering repaired, no recovery
-            # of ids the quantized scan dropped)
-            sc, ids = self._rescore(zq, wq, sc, ids, plan.k)
+            # of ids the quantized scan dropped); the coarse-pass counts
+            # ride along in its blocking copy of the scan's ids
+            reranked = int(ids.shape[1])
+            sc, ids, counts = self._rescore(zq, wq, sc, ids, plan.k, counts)
+        else:
+            reranked = 0
         with spans.span("executor.fetch"):
+            if counts:      # tiered: the counts join this copy (or came
+                            # back already with the re-rank's ids)
+                sc, ids, n, *counts = jax.device_get((sc, ids, n, *counts))
             sc, ids = pad_topk(np.asarray(sc), np.asarray(ids), plan.k)
             n = np.asarray(n)           # block until ready before timing
+        if tier is not None:
+            self._tls.tier_stats = dict(
+                hits=counts[0], survivors=counts[1],
+                survivor_slots=tier["survivor_slots"], reranked=reranked)
         if first:
             wall_ms = (time.perf_counter() - t0) * 1e3
             self._tls.compile_ms = wall_ms
@@ -921,20 +936,6 @@ class Executor:
                 self._events.publish("compile_end", plan=plan.kind,
                                      grid=list(plan.grid), n_queries=q,
                                      k=plan.k, ms=wall_ms)
-        tier = getattr(self._tls, "tier_stats", None)
-        if tier is not None and self._events is not None:
-            n_hits, n_surv = tier
-            frac = float(n_surv.mean()) / max(self.n_live, 1)
-            self._events.publish(
-                "coarse_pass", n_queries=q, n_columns=self.n_live,
-                survivor_budget=plan.survivor_budget,
-                hits_mean=float(n_hits.mean()),
-                survivors_mean=float(n_surv.mean()),
-                survivors_max=int(n_surv.max()), survivor_fraction=frac)
-            self._events.publish(
-                "fine_probe", n_queries=q, budget=plan.budget,
-                survivor_budget=plan.survivor_budget,
-                scored_mean=float(n.mean()))
         return sc, ids, n
 
     def last_compile_ms(self) -> float | None:
@@ -942,25 +943,40 @@ class Executor:
         ``execute`` call, or None when the shape was already warm."""
         return getattr(self._tls, "compile_ms", None)
 
+    def last_tier_stats(self) -> dict | None:
+        """Coarse-pass counts of this thread's most recent ``execute``
+        call when it ran a tiered plan, else None: per padded query row
+        ``hits`` (direct digest hits) and ``survivors`` (digest-eligible
+        columns, block-mates included), as numpy; ``survivor_slots``, the
+        survivor gather width per query; ``reranked``, the rows per query
+        the exact fp32 re-rank scored (0 for an fp32 scan)."""
+        return getattr(self._tls, "tier_stats", None)
+
     # -- internals ----------------------------------------------------------
 
-    def _rescore(self, zq, wq, sc, ids, k: int):
+    def _rescore(self, zq, wq, sc, ids, k: int, extra=()):
         """Gather the scan's candidate rows from the fp32 source and
         re-rank them exactly.  The gather is (Q, R, F) with R a small
-        multiple of k, so the cost is independent of the lake size."""
-        with spans.span("executor.fetch"):
-            ids_np, sc_np = np.asarray(ids), np.asarray(sc)
-        # clip to live rows, not the bucket-padded corpus: the fp32 source
-        # may be an unpadded view (invalid slots are -1 → row 0, already
-        # excluded by the scan's non-finite score)
-        safe = np.clip(ids_np, 0, self.n_live - 1)
-        with spans.span("executor.put"):
-            dyn = (self._put(zq, jnp.float32), self._put(wq),
-                   self._put(self._fp32_rows(safe), jnp.float32),
-                   self._put(self._w_np[safe]), self._gbdt,
-                   self._put(sc_np), self._put(ids_np))
-        return self._call("_rescore_exact", _rescore_exact, dyn,
-                          dict(k=k), extra=(int(ids_np.shape[1]),))
+        multiple of k, so the cost is independent of the lake size.
+        ``extra`` device arrays come to the host in the same blocking
+        copy as the scan's ids. Returns the re-rank's (scores, ids) and
+        ``extra`` as numpy, all under the ``freyja.executor.rescore``
+        span."""
+        with spans.span("executor.rescore"):
+            with spans.span("executor.fetch"):
+                ids_np, sc_np, *extra = jax.device_get((ids, sc, *extra))
+            # clip to live rows, not the bucket-padded corpus: the fp32
+            # source may be an unpadded view (invalid slots are -1 → row
+            # 0, already excluded by the scan's non-finite score)
+            safe = np.clip(ids_np, 0, self.n_live - 1)
+            with spans.span("executor.put"):
+                dyn = (self._put(zq, jnp.float32), self._put(wq),
+                       self._put(self._fp32_rows(safe), jnp.float32),
+                       self._put(self._w_np[safe]), self._gbdt,
+                       self._put(sc_np), self._put(ids_np))
+            sc, ids = self._call("_rescore_exact", _rescore_exact, dyn,
+                                 dict(k=k), extra=(int(ids_np.shape[1]),))
+        return sc, ids, tuple(extra)
 
     # -- AOT dispatch -------------------------------------------------------
 
@@ -1067,15 +1083,13 @@ class Executor:
         dyn = self._local_dyn(plan, zq, wq, tq, qid, qkeys, qcoarse)
         out = self._call(name, fn, dyn, statics)
         if plan.candidates == "tiered":
+            # the coarse-pass counts stay on the device here; execute()
+            # copies them with the batch's first blocking copy
             sc, ids, n, n_hits, n_surv = out
-            # only the event bus reads the coarse-pass counts: without
-            # one, the batch pays no blocking copy for them
-            if self._events is not None:
-                with spans.span("executor.fetch"):
-                    self._tls.tier_stats = (np.asarray(n_hits),
-                                            np.asarray(n_surv))
-            return sc, ids, n
-        return out
+            return sc, ids, n, dict(
+                hits=n_hits, survivors=n_surv,
+                survivor_slots=int(statics["survivor_budget"]))
+        return (*out, None)
 
     def _execute_sharded(self, plan, zq, wq, tq, qid, qkeys):
         corpus = self._corpus(plan)

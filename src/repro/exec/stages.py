@@ -22,8 +22,12 @@ work unchanged on a shard.
 Each stage function traces under its stage's ``jax.named_scope``
 (``candidates``, ``score``, ``merge``), so every op of a compiled pipeline
 carries its stage in its HLO metadata (``op_name``) and a device profile
-can sum op time by stage under names no compiler edit changes. Scopes
-touch metadata only, never the compiled arithmetic.
+can sum op time by stage under names no compiler edit changes. The tiered
+plan's full-lake coarse pass (proxy fill, digest probe, block expansion,
+survivor top-k) nests ``coarse`` inside ``candidates``, and the exact
+re-rank of a quantized scan runs under ``rescore``
+(``executor._rescore_exact``). Scopes touch metadata only, never the
+compiled arithmetic.
 """
 from __future__ import annotations
 
@@ -104,6 +108,7 @@ def candidate_priorities(kind: str, zq, qkeys, z, ckeys, cids, tids, tq, qid,
 
 
 @stage("candidates")
+@stage("coarse")
 def tiered_survivors(qcoarse, coarse, cids, tids, tq, qid, *,
                      survivor_budget: int, interpret: bool,
                      block_c: int = 32, proxy=None):

@@ -196,6 +196,10 @@ class DiscoveryEngine:
                           "scored_columns": 0, "scan_columns": 0,
                           "refreshes": 0, "refreshes_coalesced": 0}
         self._plan_counts: dict[str, int] = {}
+        # tiered coarse-pass totals over live query rows (``stats()``)
+        self._tier_counters = {"queries": 0, "digest_hits": 0,
+                               "digest_survivors": 0, "survivor_slots": 0,
+                               "reranked_rows": 0}
         self.last_plan = None
         self._slock = threading.Lock()
         self._head: _VersionState | None = None
@@ -237,7 +241,14 @@ class DiscoveryEngine:
     @classmethod
     def from_catalog(cls, catalog: CatalogStore, model: JoinQualityModel,
                      config: EngineConfig | None = None, mesh=None):
-        return cls(catalog.snapshot(), model, config=config, mesh=mesh)
+        """An engine over ``catalog``'s head. A quantized ``profile_dtype``
+        opens the snapshot lazily (memmapped segment, streamed quantizer,
+        re-rank rows z-scored on gather; eager where the manifest is not
+        one clean segment), so the fp32 lake never sits in host memory."""
+        config = config if config is not None else EngineConfig()
+        snapshot = (catalog.snapshot(lazy=True)
+                    if config.profile_dtype != "fp32" else catalog.snapshot())
+        return cls(snapshot, model, config=config, mesh=mesh)
 
     # -- snapshot management (MVCC) -----------------------------------------
 
@@ -774,7 +785,10 @@ class DiscoveryEngine:
         query/batch totals, cache hit/miss/admission counts, the per-plan
         query histogram, snapshot-version lifecycle (current version,
         refresh count, live pinned states), the last executed plan with
-        its modeled cost, and — when a :class:`RequestScheduler` is
+        its modeled cost, the tiered plan's coarse-pass totals over live
+        query rows (``tiered``: queries, direct digest hits,
+        digest-eligible survivors, survivor gather slots, rows the exact
+        fp32 re-rank scored), and — when a :class:`RequestScheduler` is
         attached — the scheduler's counters (queue depth, formed-batch
         size histogram, bucket hits, expirations, sheds)."""
         # one consistent snapshot: counters, cache occupancy, plan
@@ -784,6 +798,7 @@ class DiscoveryEngine:
         # admission can never see a torn view (e.g. hits+misses != queries)
         with self._slock:
             plans = dict(self._plan_counts)
+            tiered = dict(self._tier_counters)
             head = self._head
             version = head.version
             n_columns = head.snapshot.n_columns
@@ -807,6 +822,7 @@ class DiscoveryEngine:
                 "capacity": self.config.cache_entries,
             },
             "plans": plans,
+            "tiered": tiered,
             "n_columns": n_columns,
             "snapshot": {"version": version, "refreshes": c["refreshes"],
                          "live_states": live},
@@ -872,11 +888,40 @@ class DiscoveryEngine:
                                              qkeys=qkeys, qcoarse=qcoarse)
         if clock is not None:
             clock.next("finalize")
+        tier = st.executor.last_tier_stats()
+        if tier is not None:
+            self._fold_tier(tier, q, plan, ncand[:q], st.executor.n_live)
         self.last_plan = plan
         with self._slock:
             self._plan_counts[plan.kind] = \
                 self._plan_counts.get(plan.kind, 0) + q
         return sc[:q], ids[:q], ncand[:q], plan
+
+    def _fold_tier(self, tier: dict, q: int, plan, scored: np.ndarray,
+                   n_live: int) -> None:
+        """Add one tiered batch's coarse-pass counts, over its ``q`` live
+        rows, to the ``stats()["tiered"]`` totals, and publish the same
+        numbers as the ``coarse_pass`` / ``fine_probe`` events."""
+        hits, surv = tier["hits"][:q], tier["survivors"][:q]
+        with self._slock:
+            t = self._tier_counters
+            t["queries"] += q
+            t["digest_hits"] += int(hits.sum())
+            t["digest_survivors"] += int(surv.sum())
+            t["survivor_slots"] += q * tier["survivor_slots"]
+            t["reranked_rows"] += q * tier["reranked"]
+        if self.events is not None:
+            self.events.publish(
+                EV.COARSE_PASS, n_queries=q, n_columns=n_live,
+                survivor_budget=tier["survivor_slots"],
+                hits_mean=float(hits.mean()),
+                survivors_mean=float(surv.mean()),
+                survivors_max=int(surv.max()),
+                survivor_fraction=float(surv.mean()) / max(n_live, 1))
+            self.events.publish(
+                EV.FINE_PROBE, n_queries=q, budget=plan.budget,
+                survivor_budget=tier["survivor_slots"],
+                scored_mean=float(scored.mean()))
 
     def _resolve(self, requests, st: _VersionState | None = None):
         """Requests -> stacked (zq, wq, sigq, tq, qid) numpy rows."""

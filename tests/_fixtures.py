@@ -61,36 +61,28 @@ def seeded_rng(request) -> np.random.Generator:
     return np.random.default_rng(seed)
 
 
-def pruned_hlo(struct, *, q: int, c: int, budget: int, interpret: bool,
-               scopes: bool) -> str:
-    """Optimized HLO text of the ``_local_pruned`` pipeline (hybrid, k=10)
-    at batch ``q`` over ``c`` columns, with its metadata (``op_name``
-    scopes, source lines) and stack-frame tables stripped. ``struct(shape,
-    dtype)`` makes each argument's ``ShapeDtypeStruct``; ``scopes=False``
-    traces with every ``jax.named_scope`` a no-op."""
-    import contextlib
-    import re
-
-    import jax
+def _gbdt_structs(struct, trees: int = 50, depth: int = 5):
     import jax.numpy as jnp
 
-    from repro.exec import executor
+    return (struct((trees, depth), jnp.int32),
+            struct((trees, depth), jnp.float32),
+            struct((trees, 2 ** depth), jnp.float32),
+            struct((), jnp.float32))
 
-    f_num, f_words, bands, trees, depth = 21, 11, 64, 50, 5
-    args = (struct((q, f_num), jnp.float32), struct((q, f_words), jnp.uint32),
-            struct((q, bands), jnp.uint32), struct((q,), jnp.int32),
-            struct((q,), jnp.int32), struct((c, f_num), jnp.float32),
-            struct((f_num,), jnp.float32), struct((c, f_words), jnp.uint32),
-            struct((c, bands), jnp.uint32), struct((c,), jnp.int32),
-            struct((c,), jnp.int32),
-            (struct((trees, depth), jnp.int32),
-             struct((trees, depth), jnp.float32),
-             struct((trees, 2 ** depth), jnp.float32),
-             struct((), jnp.float32)))
-    statics = dict(kind="hybrid", k=10, budget=budget, interpret=interpret)
+
+def optimized_hlo(raw_fn, args, statics: dict, *, scopes: bool,
+                  strip: bool = True) -> str:
+    """Optimized HLO text of a fresh ``jit`` of ``raw_fn`` (a pipeline's
+    undecorated function) at ``args``; ``scopes=False`` traces with every
+    ``jax.named_scope`` a no-op. ``strip`` removes the metadata
+    (``op_name`` scopes, source lines) and the stack-frame tables."""
+    import contextlib
+
+    import jax
+
     # a fresh jit of the raw pipeline, so neither trace is served from the
     # other's cache
-    fn = jax.jit(lambda *a, **kw: executor._local_pruned.__wrapped__(*a, **kw),
+    fn = jax.jit(lambda *a, **kw: raw_fn(*a, **kw),
                  static_argnames=tuple(statics))
     named_scope = jax.named_scope
     if not scopes:
@@ -99,8 +91,79 @@ def pruned_hlo(struct, *, q: int, c: int, budget: int, interpret: bool,
         text = fn.lower(*args, **statics).compile().as_text()
     finally:
         jax.named_scope = named_scope
+    return strip_metadata(text) if strip else text
+
+
+def strip_metadata(text: str) -> str:
+    """HLO text without its metadata and stack-frame tables."""
+    import re
+
     # the stack-frame tables (FileNames ... StackFrames: header lines and
     # numbered rows) name source lines; the ops follow them
     text = re.sub(r"(?m)^(FileNames|FunctionNames|FileLocations|StackFrames"
                   r"|\d+ .*)\n", "", text)
     return re.sub(r",? metadata=\{[^}]*\}", "", text)
+
+
+def pruned_hlo(struct, *, q: int, c: int, budget: int, interpret: bool,
+               scopes: bool) -> str:
+    """Optimized HLO text of the ``_local_pruned`` pipeline (hybrid, k=10)
+    at batch ``q`` over ``c`` columns, with its metadata (``op_name``
+    scopes, source lines) and stack-frame tables stripped. ``struct(shape,
+    dtype)`` makes each argument's ``ShapeDtypeStruct``; ``scopes=False``
+    traces with every ``jax.named_scope`` a no-op."""
+    import jax.numpy as jnp
+
+    from repro.exec import executor
+
+    f_num, f_words, bands = 21, 11, 64
+    args = (struct((q, f_num), jnp.float32), struct((q, f_words), jnp.uint32),
+            struct((q, bands), jnp.uint32), struct((q,), jnp.int32),
+            struct((q,), jnp.int32), struct((c, f_num), jnp.float32),
+            struct((f_num,), jnp.float32), struct((c, f_words), jnp.uint32),
+            struct((c, bands), jnp.uint32), struct((c,), jnp.int32),
+            struct((c,), jnp.int32), _gbdt_structs(struct))
+    statics = dict(kind="hybrid", k=10, budget=budget, interpret=interpret)
+    return optimized_hlo(executor._local_pruned.__wrapped__, args, statics,
+                         scopes=scopes)
+
+
+def tiered_hlo(struct, *, q: int, c: int, survivors: int, interpret: bool,
+               scopes: bool, strip: bool = True) -> str:
+    """Optimized HLO text of the ``_local_tiered`` pipeline over an int8
+    sidecar (k = 40, the re-rank's over-fetch; budget = survivor budget =
+    ``survivors``, 16-lane digest, 32-column blocks) at batch ``q`` over
+    ``c`` columns; arguments as :func:`pruned_hlo`'s."""
+    import jax.numpy as jnp
+
+    from repro.exec import executor
+
+    f_num, f_words, bands, lanes = 21, 11, 64, 16
+    args = (struct((q, f_num), jnp.float32), struct((q, f_words), jnp.uint32),
+            struct((q, bands), jnp.uint32), struct((q, lanes), jnp.uint32),
+            struct((q,), jnp.int32), struct((q,), jnp.int32),
+            struct((c, f_num), jnp.int8), struct((f_num,), jnp.float32),
+            struct((c, f_words), jnp.uint32), struct((c, bands), jnp.uint32),
+            struct((c, lanes), jnp.uint32), struct((c,), jnp.int32),
+            struct((c,), jnp.int32), _gbdt_structs(struct))
+    statics = dict(k=40, budget=survivors, survivor_budget=survivors,
+                   block_c=32, interpret=interpret)
+    return optimized_hlo(executor._local_tiered.__wrapped__, args, statics,
+                         scopes=scopes, strip=strip)
+
+
+def rescore_hlo(struct, *, q: int, r: int = 40, scopes: bool,
+                strip: bool = True) -> str:
+    """Optimized HLO text of ``_rescore_exact`` (k = 10) over a (q, r)
+    over-fetched candidate set; arguments as :func:`pruned_hlo`'s."""
+    import jax.numpy as jnp
+
+    from repro.exec import executor
+
+    f_num, f_words = 21, 11
+    args = (struct((q, f_num), jnp.float32), struct((q, f_words), jnp.uint32),
+            struct((q, r, f_num), jnp.float32),
+            struct((q, r, f_words), jnp.uint32), _gbdt_structs(struct),
+            struct((q, r), jnp.float32), struct((q, r), jnp.int32))
+    return optimized_hlo(executor._rescore_exact.__wrapped__, args,
+                         dict(k=10), scopes=scopes, strip=strip)

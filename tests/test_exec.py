@@ -315,6 +315,28 @@ def test_stage_scopes_change_no_compiled_op():
     assert " gather(" in scoped and " while(" in scoped
 
 
+@pytest.mark.parametrize("program", ["tiered", "rescore"])
+def test_coarse_and_rescore_scopes_change_no_compiled_op(program):
+    """The tiered pipeline's ``coarse`` scope and the exact re-rank's
+    ``rescore`` scope label ops of the optimized HLO (one bucket of each
+    program) and change no op once the metadata is stripped."""
+    import functools
+
+    import jax
+
+    from _fixtures import rescore_hlo, strip_metadata, tiered_hlo
+
+    struct = jax.ShapeDtypeStruct
+    hlo = (functools.partial(tiered_hlo, struct, q=8, c=1024, survivors=256,
+                             interpret=True) if program == "tiered"
+           else functools.partial(rescore_hlo, struct, q=8))
+    raw = hlo(scopes=True, strip=False)
+    label = ("/candidates/coarse/" if program == "tiered"
+             else "/rescore/")
+    assert label in raw
+    assert strip_metadata(raw) == hlo(scopes=False)
+
+
 def test_executor_full_matches_rank(exec_setup):
     from repro.core import DiscoveryIndex, rank, select_queries
     lake, prof, model, ex, keys = exec_setup

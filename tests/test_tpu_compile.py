@@ -94,6 +94,28 @@ def test_stage_scopes_change_no_compiled_op(one_chip):
     assert "tpu_custom_call" in scoped
 
 
+@pytest.mark.parametrize("program", ["tiered", "rescore"])
+def test_coarse_and_rescore_scopes_change_no_compiled_op(one_chip, program):
+    """The TPU compiler's programs for one bucket (8 queries) of the tiered
+    pipeline over an int8 sidecar and of the exact re-rank are the same,
+    op for op, with and without the ``coarse`` / ``rescore`` scopes, and
+    the scoped ones carry the labels in their metadata."""
+    from _fixtures import rescore_hlo, strip_metadata, tiered_hlo
+
+    struct = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    hlo = (functools.partial(tiered_hlo, struct, q=8, c=8192, survivors=512,
+                             interpret=False) if program == "tiered"
+           else functools.partial(rescore_hlo, struct, q=8))
+    raw = hlo(scopes=True, strip=False)
+    label = ("/candidates/coarse/" if program == "tiered"
+             else "/rescore/")
+    assert label in raw
+    scoped = strip_metadata(raw)
+    assert scoped == hlo(scopes=False)
+    if program == "tiered":
+        assert "tpu_custom_call" in scoped
+
+
 def _gather_operand_shapes(hlo: str) -> list[str]:
     """Shape of the first operand of every gather in an optimized HLO
     text, fused computations included (instruction names are unique in a
