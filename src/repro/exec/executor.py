@@ -178,6 +178,14 @@ def pad_topk(scores: np.ndarray, ids: np.ndarray, k: int):
             np.pad(ids, pad, constant_values=-1))
 
 
+def _new_dispatch_stats() -> dict:
+    """Zeroed dispatch counts: AOT executable or jit fallback, and which
+    top-k selection (``stages.selection``) each dispatch's full-width
+    candidate pick ran."""
+    return {"aot": 0, "fallback": 0,
+            "selection": {"threshold": 0, "top_k": 0}}
+
+
 # ---------------------------------------------------------------------------
 # local pipelines (jitted once per shape at module level)
 # ---------------------------------------------------------------------------
@@ -369,7 +377,7 @@ class Executor:
         # shape with no entry falls back to the plain jitted pipeline.
         self._compiled: dict[tuple, object] = {}
         self._exec_cache = exec_cache
-        self._dispatch_stats = {"aot": 0, "fallback": 0}
+        self._dispatch_stats = _new_dispatch_stats()
         self._closed = False
         # observability: duck-typed event sink (anything with
         # .publish(type, **payload) — service.events.EventBus; exec stays
@@ -480,7 +488,7 @@ class Executor:
         ex._grid_meshes = dict(self._grid_meshes)
         ex._compiled = dict(self._compiled)
         ex._seen_shapes = set(self._seen_shapes)
-        ex._dispatch_stats = {"aot": 0, "fallback": 0}
+        ex._dispatch_stats = _new_dispatch_stats()
         ex._tls = threading.local()
         ex._closed = False
 
@@ -1006,8 +1014,18 @@ class Executor:
 
     def dispatch_stats(self) -> dict:
         """AOT vs jit-fallback dispatch counts — a warmed engine serving
-        only ladder shapes must show zero fallbacks (test-gated)."""
-        return dict(self._dispatch_stats)
+        only ladder shapes must show zero fallbacks (test-gated) — and,
+        under ``selection``, how many pruned or tiered dispatches picked
+        their full-width candidates by ``threshold`` or by ``top_k``."""
+        stats = dict(self._dispatch_stats)
+        stats["selection"] = dict(stats["selection"])
+        return stats
+
+    def _count_selection(self, c: int, budget: int) -> None:
+        """Count the selection one dispatch's full-width pick of
+        ``budget`` out of ``c`` columns runs (the pipelines decide it by
+        the same rule from the same static shape)."""
+        self._dispatch_stats["selection"][stages.selection(c, budget)] += 1
 
     def _local_spec(self, plan: QueryPlan, n_columns: int | None = None):
         """(name, fn, statics) of the local pipeline ``plan`` runs — one
@@ -1081,6 +1099,10 @@ class Executor:
             qcoarse = self._put(qcoarse) if qcoarse is not None else None
         name, fn, statics = self._local_spec(plan)
         dyn = self._local_dyn(plan, zq, wq, tq, qid, qkeys, qcoarse)
+        if plan.candidates != "all":
+            # a tiered plan's full-width pick is its survivor pick
+            self._count_selection(self.n_columns, statics.get(
+                "survivor_budget", statics["budget"]))
         out = self._call(name, fn, dyn, statics)
         if plan.candidates == "tiered":
             # the coarse-pass counts stay on the device here; execute()
@@ -1093,7 +1115,7 @@ class Executor:
 
     def _execute_sharded(self, plan, zq, wq, tq, qid, qkeys):
         corpus = self._corpus(plan)
-        mesh, _, qaxes = self._plan_mesh_axes(plan)
+        mesh, axes, qaxes = self._plan_mesh_axes(plan)
         # pad the batch to a multiple of the query-axis size; duplicate
         # results are sliced off below
         if qkeys is not None:
@@ -1116,6 +1138,10 @@ class Executor:
                         put(zq, np.float32), put(wq),
                         put(qkeys, np.uint32), put(tq, np.int32),
                         put(qid, np.int32))
+        if plan.candidates != "all":
+            width = corpus["z"].shape[0] // int(np.prod(
+                [mesh.shape[a] for a in axes]))
+            self._count_selection(width, min(plan.budget_per_shard, width))
         key = self._exe_key("sharded", np.asarray(zq).shape[0],
                             self._sharded_statics(plan))
         exe = self._compiled.get(key)

@@ -178,10 +178,148 @@ def gather_candidates(prio, budget: int):
     """Top-``budget`` columns by priority -> (positions (Q, M), valid (Q, M)).
 
     Positions index the local corpus axis; invalid rows (priority -inf)
-    mark budget slots the scorer must ignore.
+    mark budget slots the scorer must ignore. Wide rows take
+    :func:`threshold_top_k`, the rest ``lax.top_k`` (:func:`selection`);
+    both give the same positions and values.
     """
-    pval, pos = jax.lax.top_k(prio, budget)
+    if selection(prio.shape[1], budget) == "threshold":
+        pval, pos = threshold_top_k(prio, budget)
+    else:
+        pval, pos = jax.lax.top_k(prio, budget)
     return pos, jnp.isfinite(pval)
+
+
+# A row at least this wide, with a budget below its width, selects its top
+# ``budget`` by threshold; narrower rows keep ``lax.top_k``. Set from chip
+# readings of both on one v5e: the threshold took 0.82-0.89 of top_k's
+# time at (8-64, 65,000) -> 4,096 and 0.18-0.34 at (8-32, 745,000) ->
+# 2,048; no narrower row was read.
+THRESHOLD_MIN_COLUMNS = 65_000
+
+# Bits of the k-th largest key found per counting pass (2**bits - 1
+# thresholds counted per read of the row), and lanes per block of the
+# compaction.
+_RADIX_BITS = 2
+_SELECT_BLOCK = 128
+
+
+def selection(c: int, k: int) -> str:
+    """The top-``k`` selection ``gather_candidates`` runs over a row of
+    ``c`` columns: ``"threshold"`` or ``"top_k"`` (which also takes
+    budgets of 2**15 or more, beyond the threshold's packed counts)."""
+    wide = c >= THRESHOLD_MIN_COLUMNS and k < min(c, 1 << 15)
+    return "threshold" if wide else "top_k"
+
+
+def _flip(b):
+    """Flip the magnitude bits of negative int32s: the float32 bits ``b``
+    become keys in ``lax.top_k``'s total order, and back."""
+    return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+
+
+def order_keys(x):
+    """float32 -> int32 keys in ``lax.top_k``'s total order (-NaN < -inf <
+    ... < -0.0 < +0.0 < ... < +inf < NaN)."""
+    return _flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+
+
+def _kth_largest(keys, k: int):
+    """(Q,) k-th largest of each row of int32 ``keys``, found bit by bit
+    from the top: each pass counts, per row, the keys at or above
+    ``2**_RADIX_BITS - 1`` candidate thresholds that extend the prefix
+    found so far (in the unsigned image ``key ^ INT32_MIN``, where the
+    order is that of the keys) and keeps the largest one that ``k`` keys
+    reach. No pass sorts."""
+    q = keys.shape[0]
+    prefix = jnp.zeros((q,), jnp.uint32)
+    low = 32
+    while low > 0:
+        bits = min(_RADIX_BITS, low)
+        low -= bits
+        steps = jnp.arange(1, 2 ** bits, dtype=jnp.uint32) << low
+        cand = prefix[:, None] | steps[None]                  # (Q, T)
+        cand = jax.lax.bitcast_convert_type(cand ^ jnp.uint32(0x80000000),
+                                            jnp.int32)
+        reach = [jnp.sum(keys >= cand[:, t:t + 1], axis=1) >= k
+                 for t in range(cand.shape[1])]
+        passed = jnp.sum(jnp.stack(reach, axis=1), axis=1)    # monotone
+        prefix = prefix | (passed.astype(jnp.uint32) << low)
+    return jax.lax.bitcast_convert_type(prefix ^ jnp.uint32(0x80000000),
+                                        jnp.int32)
+
+
+def _lane_cumsum(mask):
+    """Inclusive count of True along the last axis (at most
+    ``_SELECT_BLOCK`` lanes), as one matmul with an upper-triangular
+    matrix of ones: 0/1 products summed in f32 are exact."""
+    n = mask.shape[-1]
+    upper = jnp.triu(jnp.ones((n, n), jnp.bfloat16))
+    return jnp.dot(mask.astype(jnp.bfloat16), upper,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def threshold_top_k(x, k: int):
+    """``lax.top_k(x, k)`` over float32 rows (Q, C), position for position
+    and value for value, without sorting the rows.
+
+    1. The k-th largest key ``t`` of each row (:func:`_kth_largest`).
+    2. Exactly k positions in index order: every key above ``t``, then the
+       lowest-index keys equal to it until k are taken. Per block of
+       ``_SELECT_BLOCK`` lanes, count the keys taken; a prefix over the
+       blocks gives where each block's output slots end. Each slot finds
+       its block, and the slots and equal keys before that block, by
+       counting the blocks that end at or before it; it then reads that
+       block's lanes and takes the lane its rank inside the block names.
+    3. One stable sort of the k (key, position) pairs by key, largest
+       first: ties keep the ascending position order of step 2, as
+       ``lax.top_k`` over the whole row keeps them.
+
+    No step gathers single elements: the only gather reads whole blocks.
+    """
+    q0, c = x.shape
+    q = -(-q0 // 8) * 8                     # whole tiles of 8 rows
+    blk = _SELECT_BLOCK
+    nb = -(-c // blk)
+    keys = jnp.pad(order_keys(x), ((0, q - q0), (0, nb * blk - c)),
+                   constant_values=jnp.iinfo(jnp.int32).min)
+    t = _kth_largest(keys, k)[:, None]                       # (Q, 1)
+    # (Q/8, nb, 8, blk) is the tiled layout of (Q, C) itself: blocks are
+    # counted, and each slot reads its block as one sublane of a tile,
+    # with no relayout of the row
+    tiles = keys.reshape(q // 8, 8, nb, blk).transpose(0, 2, 1, 3)
+    t4 = t.reshape(q // 8, 1, 8, 1)
+    # one count per block carries both: 256 per key above t, 1 per equal
+    both = jnp.sum(jnp.where(tiles > t4, 256, (tiles == t4).astype(jnp.int32)),
+                   axis=3).transpose(0, 2, 1).reshape(q, nb)
+    above, equal = both >> 8, both & 255
+    need = k - jnp.sum(above, axis=1, keepdims=True)         # (Q, 1) >= 1
+    eq_before = jnp.cumsum(equal, axis=1) - equal
+    take_eq = jnp.clip(need - eq_before, 0, equal)
+    taken = above + take_eq
+    ends = jnp.cumsum(taken, axis=1)                          # (Q, nb)
+    slot = jnp.arange(k, dtype=jnp.int32)
+    # blocks that end at or before slot j are exactly those before its
+    # own: their count is its block, and their sums of taken keys and of
+    # taken equal keys (both at most k) are its first slot and the equal
+    # keys taken before it
+    before = ends[:, None, :] <= slot[None, :, None]          # (Q, k, nb)
+    b = jnp.sum(before, axis=2, dtype=jnp.int32)              # (Q, k)
+    sums = jnp.sum(jnp.where(before, (taken << 16 | take_eq)[:, None], 0),
+                   axis=2)
+    first, eq0 = sums >> 16, sums & 0xFFFF
+    r = jnp.arange(q, dtype=jnp.int32)[:, None]
+    rows = tiles[r // 8, b, r % 8]                            # (Q, k, blk)
+    tt = t[:, :, None]
+    eq_rank = eq0[:, :, None] + _lane_cumsum(rows == tt) - 1
+    take = (rows > tt) | ((rows == tt) & (eq_rank < need[:, :, None]))
+    rank = _lane_cumsum(take)
+    own = (slot - first)[:, :, None]                          # rank - 1
+    lane = jnp.sum(rank <= own, axis=2)
+    key = jnp.sum(jnp.where(take & (rank == own + 1), rows, 0), axis=2)
+    rev, pos = jax.lax.sort((~key, b * blk + lane), dimension=1,
+                            is_stable=True, num_keys=1)
+    vals = jax.lax.bitcast_convert_type(_flip(~rev), jnp.float32)
+    return vals[:q0], pos[:q0]
 
 
 # ---------------------------------------------------------------------------

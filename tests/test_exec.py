@@ -460,6 +460,9 @@ def test_sharded_lsh_acceptance_8dev():
         r_lo = eng_lo.query_batch(list(reqs))
         assert eng_sh.stats()["last_plan"]["kind"] == "sharded-hybrid"
         assert eng_lo.stats()["last_plan"]["kind"] == "local-hybrid"
+        # each shard's candidate pick is narrow: counted as a top_k
+        sel = eng_sh._executor.dispatch_stats()["selection"]
+        assert sel["threshold"] == 0 and sel["top_k"] >= 1, sel
 
         # parity: sharded and local pruning agree on the neighborhoods
         overlap = np.mean([

@@ -472,7 +472,8 @@ for i, m in enumerate(meshes):
     ex.aot_compile([(plan, 5)])
     got = ex.execute(plan, z[q], w[q], np.full(5, -1, np.int32),
                      q.astype(np.int32), keys[q])
-    assert ex.dispatch_stats() == {"aot": 1, "fallback": 0}
+    assert ex.dispatch_stats() == {"aot": 1, "fallback": 0,
+                                   "selection": {"threshold": 0, "top_k": 1}}
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
 print("ok")

@@ -143,3 +143,50 @@ def test_compile_scorer_reads_no_leaf_table_by_gather(one_chip):
     # a leaf row, or the whole leaf table, has 2^D values on its last axis
     assert not [o for o in operands if re.search(rf"[\[,]{2 ** d}\]$", o)], \
         operands
+
+
+def _sorts(hlo: str) -> list[tuple[list[int], str]]:
+    """(first operand's dims, ``op_name``) of every sort in an optimized
+    HLO text that keeps its metadata."""
+    out = []
+    for line in hlo.splitlines():
+        if " sort(" not in line:
+            continue
+        dims = re.search(r" = \(?\w+\[([\d,]*)\]", line).group(1)
+        name = re.search(r'op_name="([^"]*)"', line)
+        out.append(([int(d) for d in dims.split(",")],
+                    name.group(1) if name else ""))
+    return out
+
+
+def test_compile_tiered_survivor_pick_sorts_no_wide_row(one_chip):
+    """``_local_tiered`` at bucket 32 over 745,000 columns, survivor budget
+    2,048: the survivor pick runs the threshold selection, so no sort in
+    the program is wider than 2,048 (``lax.top_k`` there was split into
+    sorts of ``[Q·128,5821]`` and ``[Q,262144]``); the fine pass's
+    (32, 2,048) ``top_k`` is kept."""
+    from _fixtures import tiered_hlo
+
+    assert stages.selection(745_000, 2048) == "threshold"
+    struct = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    sorts = _sorts(tiered_hlo(struct, q=32, c=745_000, survivors=2048,
+                              interpret=False, scopes=True, strip=False))
+    assert sorts and all(d[-1] <= 2048 for d, _ in sorts), sorts
+    fine = [d for d, name in sorts
+            if name.endswith("/candidates/top_k") and "/coarse/" not in name]
+    assert fine == [[32, 2048]], sorts
+    assert any("/candidates/coarse/" in name for _, name in sorts), sorts
+
+
+def test_compile_pruned_candidate_pick_sorts_no_wide_row(one_chip):
+    """``_local_pruned`` at the ``closed64`` bucket (64 queries over 65,000
+    columns, budget 4,096): the candidate pick runs the threshold
+    selection, so no sort is wider than the budget (``lax.top_k`` sorted
+    the whole (64, 65,000) row)."""
+    from _fixtures import pruned_hlo
+
+    assert stages.selection(65_000, 4096) == "threshold"
+    struct = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    sorts = _sorts(pruned_hlo(struct, q=64, c=65_000, budget=4096,
+                              interpret=False, scopes=True))
+    assert sorts and all(d[-1] <= 4096 for d, _ in sorts), sorts
